@@ -657,8 +657,9 @@ def serve_bench(smoke: bool = False, out: str = "BENCH_engine.json",
          f"(misses={stats['misses']} hits={stats['hits']}) -> {out}")
 
 
-if __name__ == "__main__":
+def main() -> None:
     from repro.core.backend import list_backends
+    from repro.launch.compile_cache import enable_compile_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes for CI (seconds, not minutes)")
@@ -676,6 +677,7 @@ if __name__ == "__main__":
                     help="DEPRECATED alias: 'engine' = host series only, "
                     "'engine_jit' = host + device series (use --backends)")
     args = ap.parse_args()
+    enable_compile_cache()
     backends = args.backends.split(",") if args.backends else None
     if args.path is not None and backends is None:
         warnings.warn("--path is deprecated; use --backends",
@@ -686,3 +688,7 @@ if __name__ == "__main__":
         serve_bench(smoke=args.smoke, out=args.json, backends=backends)
     else:
         run(smoke=args.smoke)
+
+
+if __name__ == "__main__":
+    main()

@@ -11,6 +11,7 @@ import time
 from benchmarks import (bench_attention, bench_dse, bench_energy_area,
                         bench_fc, bench_kernel, bench_resnet,
                         bench_roofline, bench_scoreboard)
+from repro.launch.compile_cache import enable_compile_cache
 
 SECTIONS = {
     "dse": bench_dse.run,                # Fig. 9
@@ -25,6 +26,7 @@ SECTIONS = {
 
 
 def main() -> None:
+    enable_compile_cache()
     picks = sys.argv[1:] or list(SECTIONS)
     print("name,us_per_call,derived")
     t0 = time.perf_counter()

@@ -23,7 +23,7 @@ Three entry points:
 from __future__ import annotations
 
 import jax
-from jax import core
+from jax.extend import core
 
 from repro.analysis.baseline import (load_baseline, save_baseline,
                                      split_baselined, stale_keys)

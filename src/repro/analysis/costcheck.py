@@ -36,6 +36,7 @@ import os
 from typing import Any, Callable, Iterator
 
 import numpy as np
+from jax.extend.core import Literal
 
 from repro.analysis.rules import Finding
 from repro.analysis.walker import LOOP_PRIMS, SCATTER_PRIMS, subjaxprs
@@ -108,12 +109,11 @@ def _inner(jaxpr: Any) -> Any:
 def _walk(jaxpr: Any, view_in: list[bool], weight: float, in_loop: bool,
           guarded: bool, acc: CostMetrics) -> list[bool]:
     """Accumulate costs; returns which outvars are pool views."""
-    from jax import core
     j = _inner(jaxpr)
     views = {v for v, t in zip(j.invars, view_in) if t}
     for eqn in j.eqns:
         name = eqn.primitive.name
-        inv = [(not isinstance(v, core.Literal)) and v in views
+        inv = [(not isinstance(v, Literal)) and v in views
                for v in eqn.invars]
         acc.eqns += 1
         acc.eqns_dynamic += weight
@@ -158,7 +158,7 @@ def _walk(jaxpr: Any, view_in: list[bool], weight: float, in_loop: bool,
         if not entered and name in VIEW_PRIMS and inv and inv[0]:
             for v in eqn.outvars:
                 views.add(v)
-    return [(not isinstance(v, core.Literal)) and v in views
+    return [(not isinstance(v, Literal)) and v in views
             for v in j.outvars]
 
 
@@ -169,16 +169,15 @@ def _peak_live_bytes(jaxpr: Any) -> int:
     (outputs at the end) — a coarse upper-structure metric, but it is
     signature-determined and moves when someone materialises a second
     KV cache."""
-    from jax import core
     j = _inner(jaxpr)
     last_use: dict[Any, int] = {}
     n = len(j.eqns)
     for i, eqn in enumerate(j.eqns):
         for v in eqn.invars:
-            if not isinstance(v, core.Literal):
+            if not isinstance(v, Literal):
                 last_use[v] = i
     for v in j.outvars:
-        if not isinstance(v, core.Literal):
+        if not isinstance(v, Literal):
             last_use[v] = n
     live = {v: _aval_bytes(v) for v in j.invars}
     peak = cur = sum(live.values())

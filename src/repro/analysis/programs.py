@@ -21,7 +21,6 @@ import contextlib
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.analysis.rules import Finding, LintProgram, run_rules
 from repro.core.backend import EngineConfig, get_backend
 
@@ -90,7 +89,7 @@ def build_programs(backend_name: str, *, mesh=None, arch: str = "smollm-135m",
     batch_d = {"tokens": jax.random.randint(
         jax.random.PRNGKey(1), (batch, prompt_len), 0, cfg.vocab,
         jnp.int32)}
-    ctx = jax_compat.set_mesh(mesh) if mesh is not None \
+    ctx = jax.set_mesh(mesh) if mesh is not None \
         else contextlib.nullcontext()
     n_params = _n_leaves(params)
     progs: list[LintProgram] = []

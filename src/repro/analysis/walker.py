@@ -27,7 +27,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Iterator
 
-from jax import core
+from jax.extend import core
 
 __all__ = ["EqnSite", "iter_eqns", "subjaxprs", "CALLBACK_PRIMS",
            "SCATTER_PRIMS", "LOOP_PRIMS", "CALL_PRIMS"]
@@ -51,7 +51,7 @@ CALL_PRIMS = frozenset({"pjit", "cond", "remat2", "custom_jvp_call",
 @dataclasses.dataclass(frozen=True)
 class EqnSite:
     """One visited equation with its structural context."""
-    eqn: Any                      # jax.core.JaxprEqn
+    eqn: Any                      # jax.extend.core.JaxprEqn
     path: str                     # "12:scan/jaxpr/0:scatter"
     in_loop: bool                 # inside any scan/while body
     scopes: frozenset[str]        # inherited named_scope components

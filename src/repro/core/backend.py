@@ -360,7 +360,6 @@ class EngineHostBackend(TransitiveBackend):
                                              backend=self.name)
 
     def execute(self, x, w, plan, dplan, cfg):
-        from repro import jax_compat
         if plan is not None and (plan.bits, plan.t,
                                  plan.groups) != cfg.key():
             raise ValueError(
@@ -382,8 +381,8 @@ class EngineHostBackend(TransitiveBackend):
                 return (part.transpose(2, 1, 0)
                         .reshape(xg_np.shape[:-1] + (n,)).astype(np.int32))
 
-            return jax_compat.pure_callback(host, out, x, w,
-                                            vmap_method="expand_dims")
+            return jax.pure_callback(host, out, x, w,
+                                     vmap_method="expand_dims")
 
         out = jax.ShapeDtypeStruct(x.shape[:-1] + (w.shape[0],), jnp.int32)
 
@@ -394,8 +393,8 @@ class EngineHostBackend(TransitiveBackend):
             return (y.reshape(qx_np.shape[:-1] + (qw2.shape[0],))
                     .astype(np.int32))
 
-        return jax_compat.pure_callback(host, out, x, w,
-                                        vmap_method="expand_dims")
+        return jax.pure_callback(host, out, x, w,
+                                 vmap_method="expand_dims")
 
 
 class EngineJitBackend(TransitiveBackend):

@@ -28,10 +28,8 @@ import warnings
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
-
 __all__ = ["RULES", "ShardingDropWarning", "spec", "shard",
-           "mesh_axis_size"]
+           "mesh_axis_size", "ambient_mesh"]
 
 
 class ShardingDropWarning(UserWarning):
@@ -73,12 +71,14 @@ RULES: dict[str, tuple[str, ...]] = {
 }
 
 
-def _ambient_mesh():
-    return jax_compat.get_abstract_mesh()
+def ambient_mesh():
+    """The ambient mesh, or None when no mesh context is active."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def mesh_axis_size(name: str) -> int:
-    m = _ambient_mesh()
+    m = ambient_mesh()
     if m is None or name not in m.axis_names:
         return 1
     return m.shape[name]
@@ -95,7 +95,7 @@ def spec(*logical_axes: str | None, shape: tuple[int, ...] | None = None,
     once per (rule, extent, dim) — replication is a legal fallback, not a
     silent one. ``mesh`` defaults to the ambient mesh.
     """
-    m = _ambient_mesh() if mesh is None else mesh
+    m = ambient_mesh() if mesh is None else mesh
     parts = []
     for i, name in enumerate(logical_axes):
         if name is None or name == "none":
@@ -120,7 +120,7 @@ def spec(*logical_axes: str | None, shape: tuple[int, ...] | None = None,
 
 def shard(x: jax.Array, *logical_axes: str | None) -> jax.Array:
     """with_sharding_constraint under the ambient mesh; no-op without one."""
-    if _ambient_mesh() is None:
+    if ambient_mesh() is None:
         return x
     assert len(logical_axes) == x.ndim, (logical_axes, x.shape)
     s = spec(*logical_axes, shape=x.shape)

@@ -50,7 +50,7 @@ def _kernel(x_ref, a_ref, h0_ref, out_ref, carry_ref, *, bs):
 @functools.partial(jax.jit, static_argnames=("bb", "bs", "bd", "interpret"))
 def rg_lru_pallas(x: jnp.ndarray, a: jnp.ndarray, h0: jnp.ndarray, *,
                   bb: int = 8, bs: int = 256, bd: int = 256,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool) -> jnp.ndarray:
     """x, a: (B, S, D); h0: (B, D) -> h: (B, S, D)."""
     b, s, d = x.shape
     bb, bs, bd = min(bb, b), min(bs, s), min(bd, d)

@@ -46,7 +46,7 @@ def _kernel(x_ref, src_ref, xsrc_ref, didx_ref, dxidx_ref,
                                              "interpret"))
 def transitive_forest_pallas(x, level_src, level_xsrc, direct_idx,
                              direct_x_idx, direct_bits, gather_idx, signs, *,
-                             t, groups, n, k, bm, interpret=True):
+                             t, groups, n, k, bm, interpret):
     """Raw pallas_call over the plan leaves; x (K, M) with M % bm == 0."""
     m = x.shape[1]
     full = lambda a: pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)

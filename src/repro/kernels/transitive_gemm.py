@@ -85,7 +85,7 @@ def transitive_gemm_pallas(qx: jnp.ndarray, qw: jnp.ndarray, *,
                            w_bits: int = 8, t: int = 8,
                            bm: int = 128, bn: int = 64, bk: int = 256,
                            split_lut: bool = True,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool) -> jnp.ndarray:
     """int32 [qx (M, K) i8] @ [qw (N, K) i8]^T with transitive reuse.
 
     M, N, K must be divisible by (bm, bn, bk); ops.py handles padding.

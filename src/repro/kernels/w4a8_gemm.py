@@ -51,7 +51,7 @@ def _kernel(x_ref, w_ref, sg_ref, sx_ref, out_ref, *, bk, group, nk):
 def w4a8_gemm_pallas(qx: jnp.ndarray, sx: jnp.ndarray, qw: jnp.ndarray,
                      sg: jnp.ndarray, *, group: int = 128,
                      bm: int = 128, bn: int = 128, bk: int = 512,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool) -> jnp.ndarray:
     """f32 (M, N) = dequant(qw, sg) @ qx^T-style fused GEMM.
 
     qx (M, K) i8, sx (M, 1) f32 per-token scales,

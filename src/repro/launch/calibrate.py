@@ -27,7 +27,6 @@ import traceback
 
 import jax
 
-from repro import jax_compat
 from repro.configs import get_config
 from repro.configs.base import SHAPES
 from repro.launch.dryrun import (DRYRUN_ARCHS, cell_skip_reason, lower_train,
@@ -38,7 +37,7 @@ from repro.models import attention, model
 
 
 def _measure(cfg, shape, mesh):
-    with jax_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             lowered = lower_train(cfg, shape, mesh)
         elif shape.kind == "prefill":

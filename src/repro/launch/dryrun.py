@@ -24,7 +24,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.configs import ARCHS, get_config
 from repro.configs.base import SHAPES
 from repro.launch.mesh import make_production_mesh
@@ -96,7 +95,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
                        f" encoder frames)")
     t0 = time.time()
     try:
-        with jax_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 lowered = lower_train(cfg, shape, mesh)
             elif shape.kind == "prefill":

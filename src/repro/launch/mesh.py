@@ -1,17 +1,23 @@
 """Production mesh construction (pure functions — importing this module
-never touches jax device state). Mesh creation goes through
-repro.jax_compat so the same code imports on old (no AxisType) and new
-JAX. ``make_serve_mesh`` is the serve-cell entry point: it takes the
-``--mesh data=4`` CLI spelling and builds a mesh over a *prefix* of the
-local devices (unlike ``jax.make_mesh`` it does not require the axis
-product to cover every device — a 2-way cell on a 4-device host is
-legal)."""
+never touches jax device state). ``make_serve_mesh`` is the serve-cell
+entry point: it takes the ``--mesh data=4`` CLI spelling and builds a
+mesh over a *prefix* of the local devices (unlike ``jax.make_mesh`` it
+does not require the axis product to cover every device — a 2-way cell
+on a 4-device host is legal)."""
 from __future__ import annotations
 
-from repro import jax_compat
+import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh", "parse_mesh_spec",
-           "make_serve_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh",
+           "parse_mesh_spec", "make_serve_mesh"]
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules here rely
+    on GSPMD propagation, and JAX 0.9 defaults to ``Explicit`` axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,12 +28,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax_compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many local devices exist (tests)."""
-    return jax_compat.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_spec(arg: str) -> dict[str, int]:
@@ -57,7 +63,6 @@ def make_serve_mesh(spec: str | dict[str, int]):
     order), so a cell smaller than the host is legal. On a CPU host,
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` fakes N
     devices — the tests/CI topology."""
-    import jax
     import numpy as np
 
     axes = parse_mesh_spec(spec) if isinstance(spec, str) else dict(spec)

@@ -76,10 +76,18 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_reduced
 from repro.core.backend import get_backend, list_backends
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serve_mesh
 from repro.launch.specs import mesh_decode_report, serve_config
 from repro.models.model import Model
 from repro.train.serve_step import greedy_generate
+
+
+def device_line() -> str:
+    """The device every report names: platform, kind and count."""
+    devs = jax.devices()
+    return (f"[device] platform={devs[0].platform} "
+            f"kind={devs[0].device_kind} count={len(devs)}")
 
 
 def _serve_continuous(model, params, cfg, args, mesh, name,
@@ -171,6 +179,7 @@ def _serve_continuous(model, params, cfg, args, mesh, name,
           f"x {args.gen} tokens (staggered every {args.arrive_every} steps, "
           f"{args.slots} slots, page_size={ps}) in {dt:.2f}s -> "
           f"{rep['tokens_per_s']:.1f} tok/s")
+    print(device_line())
     for r in rep["requests"]:
         print(f"  req {r['rid']}: prompt={r['prompt_len']} "
               f"tokens={r['n_tokens']} shared_pages={r['shared_pages']} "
@@ -317,6 +326,7 @@ def main():
                     "finished request bit-matches the one-shot path on "
                     "its own generation's weights")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.role is not None and not args.bundle_dir:
         ap.error(f"--role {args.role} needs --bundle-dir")
     if args.watch_weights and not args.continuous:
@@ -452,6 +462,7 @@ def main():
     mode = "fp" if args.fp else f"W{args.w_bits}A8+KV8/{name}"
     print(f"[{cfg.name} | {mode}] generated {args.batch}x{args.gen} tokens "
           f"in {dt:.2f}s")
+    print(device_line())
     if mesh is not None:
         print(mesh_decode_report(mesh, args.batch, args.gen, dt))
     if planned:

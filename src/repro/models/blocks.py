@@ -15,9 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import shard
+from repro.distributed.sharding import ambient_mesh, shard
 from repro.models.attention import rms_norm
 from repro.quant import linear_init, linear_apply
 
@@ -120,7 +119,7 @@ def apply_moe(params, x, cfg: ModelConfig):
     gates, eids = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.top_k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    mesh = jax_compat.get_abstract_mesh()
+    mesh = ambient_mesh()
     ep = (mesh is not None and "model" in mesh.axis_names
           and cfg.n_experts % mesh.shape["model"] == 0)
 
@@ -154,7 +153,7 @@ def apply_moe(params, x, cfg: ModelConfig):
             return y.reshape(bl, sl, d)
 
         wspec = P(None, "model", None, None)
-        y = jax_compat.shard_map(
+        y = jax.shard_map(
             ep_fn, mesh=mesh,
             in_specs=(xspec, xspec, xspec, wspec, wspec, wspec),
             out_specs=xspec, check_vma=False,

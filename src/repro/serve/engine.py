@@ -74,7 +74,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import jax_compat
 from repro.models.attention import CHUNK_THRESHOLD
 from repro.models.model import Model
 from repro.serve.paging import PageAllocator, PrefixTrie
@@ -426,7 +425,7 @@ class ServeEngine:
 
     # -- scheduling --------------------------------------------------------
     def _mesh_ctx(self):
-        return (jax_compat.set_mesh(self.mesh) if self.mesh is not None
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
 
     def _alloc_page(self, cell: _Cell) -> int | None:

@@ -28,7 +28,6 @@ import weakref
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.distributed.sharding import spec
 from repro.models.model import Model
 
@@ -120,7 +119,7 @@ def greedy_generate(model: Model, params, batch, max_len: int,
     b, prompt_len = batch["tokens"].shape
     if n_steps == 0:
         return jnp.zeros((b, 0), jnp.int32)
-    ctx = jax_compat.set_mesh(mesh) if mesh is not None \
+    ctx = jax.set_mesh(mesh) if mesh is not None \
         else contextlib.nullcontext()
     with ctx:
         if mesh is not None:

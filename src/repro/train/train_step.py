@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
 from repro.configs.base import ModelConfig
 from repro.distributed.collectives import compressed_psum_tree
 from repro.models.model import Model
@@ -131,7 +130,7 @@ def make_compressed_dp_train_step(model: Model, opt: AdamW, lr_fn, mesh,
         state_specs = specs_like(state, rep)
         batch_specs = jax.tree.map(
             lambda a: P(None, dp_axes, *([None] * (a.ndim - 2))), batch)
-        return jax_compat.shard_map(local_step, mesh=mesh,
+        return jax.shard_map(local_step, mesh=mesh,
                              in_specs=(state_specs, batch_specs),
                              out_specs=(state_specs, specs_like(
                                  {"loss": 0}, rep)),

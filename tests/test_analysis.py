@@ -218,7 +218,7 @@ def test_control_dtype_purity_fires_on_bf16_in_quantize_scope():
 
 
 def test_control_dtype_purity_fires_on_f64_anywhere():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.ones((4,), jnp.float64))
     found = analysis.find_violations(jaxpr, rules=("dtype-purity",))

@@ -1,6 +1,6 @@
 """Bit-slicing properties (paper Sec. 2.1-2.2): exact roundtrips."""
 import numpy as np
-from _compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bitslice
 

@@ -26,7 +26,6 @@ def test_sharded_train_step_runs():
     mesh: loss finite, params update, state donated."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import jax_compat
         from repro.configs import get_reduced
         from repro.launch.mesh import make_local_mesh
         from repro.train.train_step import (init_state, make_optimizer,
@@ -38,7 +37,7 @@ def test_sharded_train_step_runs():
         cfg = get_reduced("qwen3_14b")
         mesh = make_local_mesh(2, 4)
         model, opt = Model(cfg), make_optimizer(cfg)
-        with jax_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = init_state(model, opt, jax.random.PRNGKey(0))
             step = jax.jit(make_train_step(model, opt,
                            cosine_schedule(1e-3, 2, 100)), donate_argnums=0)
@@ -61,7 +60,6 @@ def test_moe_ep_matches_local():
     capacity so nothing drops)."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import jax_compat
         from repro.configs import get_reduced
         from repro.launch.mesh import make_local_mesh
         from repro.models import blocks as B
@@ -73,7 +71,7 @@ def test_moe_ep_matches_local():
                               jnp.float32) * 0.3
         y_local = np.asarray(B.apply_moe(p, x, cfg), np.float32)
         mesh = make_local_mesh(2, 4)
-        with jax_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_ep = np.asarray(jax.jit(
                 lambda pp, xx: B.apply_moe(pp, xx, cfg))(p, x), np.float32)
         err = np.abs(y_ep - y_local).max()
@@ -90,18 +88,18 @@ def test_compressed_pod_psum():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import jax_compat
         from repro.distributed.collectives import compressed_psum_tree
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax_compat.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         g = {"a": jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 7.0}
         r = {"a": jnp.zeros((8, 8), jnp.float32)}
 
         def f(g, r):
             return compressed_psum_tree(g, r, "pod")
 
-        with jax_compat.set_mesh(mesh):
-            red, res = jax.jit(jax_compat.shard_map(
+        with jax.set_mesh(mesh):
+            red, res = jax.jit(jax.shard_map(
                 f, mesh=mesh,
                 in_specs=({"a": P()}, {"a": P()}),
                 out_specs=({"a": P()}, {"a": P()}),
@@ -146,7 +144,6 @@ def test_serve_decode_sharded():
     """Sharded decode step executes on a small mesh (quantized serve cfg)."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro import jax_compat
         from repro.configs import get_reduced
         from repro.launch.mesh import make_local_mesh
         from repro.launch.specs import serve_config
@@ -155,7 +152,7 @@ def test_serve_decode_sharded():
         cfg = serve_config(get_reduced("chatglm3_6b"))
         m = Model(cfg)
         mesh = make_local_mesh(2, 4)
-        with jax_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = m.init(jax.random.PRNGKey(0))
             batch = {"tokens": jnp.ones((4, 16), jnp.int32)}
             logits, caches = jax.jit(

@@ -25,10 +25,13 @@ def test_transitive_gemm_split_vs_full_lut(rng):
     from repro.kernels.transitive_gemm import transitive_gemm_pallas
     qx = rng.integers(-128, 128, (16, 64)).astype(np.int8)
     qw = rng.integers(-8, 8, (16, 64)).astype(np.int8)
+    interp = ops.default_interpret()
     a = transitive_gemm_pallas(jnp.asarray(qx), jnp.asarray(qw), w_bits=4,
-                               t=8, bm=8, bn=8, bk=8, split_lut=True)
+                               t=8, bm=8, bn=8, bk=8, split_lut=True,
+                               interpret=interp)
     b = transitive_gemm_pallas(jnp.asarray(qx), jnp.asarray(qw), w_bits=4,
-                               t=8, bm=8, bn=8, bk=8, split_lut=False)
+                               t=8, bm=8, bn=8, bk=8, split_lut=False,
+                               interpret=interp)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -76,3 +79,21 @@ def test_lut_build_matches_subset_sums(rng):
     for p in [0, 1, 5, 128, 255, 170]:
         bits = [b for b in range(8) if (p >> b) & 1]
         np.testing.assert_array_equal(lut[:, p], x[:, bits].sum(-1))
+
+
+@pytest.mark.parametrize("module,builder", [
+    ("transitive_gemm", "transitive_gemm_pallas"),
+    ("transitive_forest", "transitive_forest_pallas"),
+    ("w4a8_gemm", "w4a8_gemm_pallas"),
+    ("rg_lru", "rg_lru_pallas"),
+])
+def test_raw_builder_requires_interpret(module, builder):
+    """The raw pallas_call builders take no interpret default: only
+    ops.default_interpret() decides, so nothing lands in interpret mode
+    on a TPU because a caller forgot the argument."""
+    import importlib
+    import inspect
+    fn = getattr(importlib.import_module(f"repro.kernels.{module}"), builder)
+    param = inspect.signature(fn).parameters["interpret"]
+    assert param.kind is inspect.Parameter.KEYWORD_ONLY
+    assert param.default is inspect.Parameter.empty

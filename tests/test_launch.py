@@ -99,3 +99,39 @@ def test_param_specs_shapes_align():
     assert len(flat_s) == len(flat_p)
     for sp, p in zip(flat_s, flat_p):
         assert len(sp) <= p.ndim
+
+
+@pytest.fixture
+def cache_dir_config():
+    """Restore JAX's compile-cache directory after the test."""
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+@pytest.mark.parametrize("env", [None, "outside"])
+def test_compile_cache_placement(env, monkeypatch, tmp_path,
+                                 cache_dir_config):
+    """The entry points' compile cache: where JAX_COMPILATION_CACHE_DIR
+    is set, JAX has read it and nothing overrides it; otherwise a fixed
+    <checkout>/.jax_cache that git ignores."""
+    import pathlib
+    import subprocess
+    from repro.launch.compile_cache import (DEFAULT_CACHE_DIR,
+                                            enable_compile_cache)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert DEFAULT_CACHE_DIR == root / ".jax_cache"
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert enable_compile_cache() == str(DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
+        ignored = subprocess.run(
+            ["git", "check-ignore", "-q", str(DEFAULT_CACHE_DIR / "x")],
+            cwd=root, check=False)
+        assert ignored.returncode in (0, 128), "git must ignore .jax_cache"
+    else:
+        outside = str(tmp_path / env)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        jax.config.update("jax_compilation_cache_dir", outside)  # as read
+        assert enable_compile_cache() == outside
+        assert jax.config.jax_compilation_cache_dir == outside

@@ -3,7 +3,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.quant.quantize as Q
 from repro.quant import QuantConfig, linear_init, linear_apply

@@ -1,6 +1,6 @@
 """Scoreboard invariants (paper Sec. 3, Fig. 5) — property-based."""
 import numpy as np
-from _compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import hasse
 from repro.core.patterns import tile_stats

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import analysis, jax_compat
+from repro import analysis
 from repro.configs import get_reduced
 from repro.distributed import sharding as SH
 from repro.launch.mesh import make_serve_mesh, parse_mesh_spec
@@ -298,7 +298,7 @@ def test_mesh_decode_jaxpr_callback_free_and_caches_sharded(cache):
     model, params, batch = _quant_cell("engine_jit")
     mesh = _data_mesh(4)
     params_m = model.attach_device_plans(params, mesh=mesh)
-    with jax_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         placed = _place_batch(batch, mesh)
         logits, caches = _jit_prefill(model, 24)(params_m, placed)
         for leaf in jax.tree_util.tree_leaves(caches["body"]):

@@ -1,0 +1,108 @@
+"""Compile the serving path's device programs for a TPU v5e, at the full
+published width of smollm-135m, without a chip.
+
+The TPU compiler is installed with jaxlib and compiles for a described,
+unattached topology. It refuses what interpret mode accepts: unaligned
+blocks, kernels that need more fast memory than a core has, programs that
+do not fit the device. Nothing here runs, so these tests say nothing about
+results or time; ``chip_smoke.py`` runs the same programs on a chip.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and pytest-xdist workers all
+import this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.launch.specs import serve_config
+from repro.models.model import Model
+
+HBM_BYTES = 16 * 2**30          # one v5e chip
+SLOTS, MAX_LEN, PAGE = 8, 2048, 16
+PREFILL_ROWS, PREFILL_LEN = 8, 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A program compiled for a described chip is written to the
+    persistent cache but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def served(one_chip, no_compile_cache):
+    """Full-width smollm-135m as ``launch/serve.py`` builds it, as shapes
+    placed on one described v5e chip, with an engine-sized page pool."""
+    cfg = serve_config(get_config("smollm-135m"), w_bits=4,
+                       backend="int_dot")
+    model = Model(cfg)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    n_pages = SLOTS * MAX_LEN // PAGE + 1      # ServeEngine's default
+    pool = place(jax.eval_shape(
+        functools.partial(model.init_page_pool, n_pages, PAGE)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    return model, params, pool, i32
+
+
+def _check_fits(compiled):
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < used < HBM_BYTES, used
+
+
+def test_paged_decode_compiles_for_v5e(served):
+    """The packed decode ``ServeEngine`` jits: gather path, ``int_dot``,
+    8 slots x 2048 positions, pool donated."""
+    model, params, pool, i32 = served
+    decode = jax.jit(model.decode_step_paged, static_argnames=("kernel",),
+                     donate_argnums=(1,))
+    compiled = decode.lower(params, pool, i32(SLOTS, 1),
+                            i32(SLOTS, MAX_LEN // PAGE), i32(SLOTS),
+                            kernel=False).compile()
+    _check_fits(compiled)
+
+
+def test_bucketed_prefill_compiles_for_v5e(served):
+    """One bucketed batched-prefill program: 8 rows x 256 positions, no
+    shared prefix, pool donated."""
+    model, params, pool, i32 = served
+    prefill = jax.jit(model.prefill_paged_batched, donate_argnums=(2,))
+    rows, lb = PREFILL_ROWS, PREFILL_LEN
+    compiled = prefill.lower(
+        params, i32(rows, lb), pool, prefix_page_ids=i32(rows, 0),
+        prefix_lens=i32(rows), suffix_lens=i32(rows),
+        write_page_ids=i32(rows, lb), write_offs=i32(rows, lb),
+        write_pos=i32(rows, lb)).compile()
+    _check_fits(compiled)

@@ -5,7 +5,7 @@ distributions — including adversarial all-ones/all-zeros/duplicate-heavy
 matrices.
 """
 import numpy as np
-from _compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import transitive
 
