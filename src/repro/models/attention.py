@@ -99,6 +99,7 @@ def _pv(p, v, quant: bool):
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
+@jax.named_scope("attention")
 def attend_full(q, k, v, mask, scale, quant: bool = False):
     """Direct softmax attention. q (B,Sq,H,D), k/v (B,Sk,KV*,D) pre-repeat."""
     s = _scores(q, k, scale, quant)
@@ -107,6 +108,7 @@ def attend_full(q, k, v, mask, scale, quant: bool = False):
     return _pv(p, v, quant)
 
 
+@jax.named_scope("attention")
 def attend_chunked(q, k, v, scale, causal: bool, window: int,
                    q_offset: int | jnp.ndarray = 0,
                    kv_len: jnp.ndarray | None = None):
@@ -147,6 +149,7 @@ def attend_chunked(q, k, v, scale, causal: bool, window: int,
     return jnp.moveaxis(outs, 0, 1).reshape(b, sq, h, d)  # (B, Sq, H, D)
 
 
+@jax.named_scope("attention")
 def attend_cached(q, ck, cv, cks, cvs, valid, cfg: ModelConfig, scale,
                   sshard=None):
     """Decode-step attention against a contiguous (B, S, KV, D) cache view.
@@ -407,6 +410,7 @@ def init_attn_page_pool(cfg: ModelConfig, n_pages: int, page_size: int):
             "v": jnp.zeros(shape, cfg.dtype)}
 
 
+@jax.named_scope("kv_gather")
 def _gather_pages(buf, page_indices):
     """(n_pages, ps, ...) gathered to a contiguous (B, P*ps, ...) view in
     logical-position order — position p of slot b lands at index p, so the
@@ -474,8 +478,8 @@ def apply_attn_paged_prefill(params, x, cfg: ModelConfig, *, pool,
     # full K/V view: gathered shared prefix (exact working-dtype pools
     # only — the engine guarantees n_pre == 0 for KV8) + in-pass suffix
     if n_pre:
-        k_pre = pool["k"][prefix_page_ids].reshape(1, start, kvh, hd)
-        v_pre = pool["v"][prefix_page_ids].reshape(1, start, kvh, hd)
+        k_pre = _gather_pages(pool["k"], prefix_page_ids[None])
+        v_pre = _gather_pages(pool["v"], prefix_page_ids[None])
         k_full = jnp.concatenate([k_pre.astype(k.dtype), k], axis=1)
         v_full = jnp.concatenate([v_pre.astype(v.dtype), v], axis=1)
     else:
@@ -574,8 +578,8 @@ def apply_attn_paged_prefill_batched(params, x, cfg: ModelConfig, *, pool,
     suf_valid = suf_idx[None, :] < suffix_lens[:, None]         # (B, Lb)
     if n_pre:
         pre_valid = jnp.arange(start)[None, :] < prefix_lens[:, None]
-        k_pre = pool["k"][prefix_page_ids].reshape(b, start, kvh, hd)
-        v_pre = pool["v"][prefix_page_ids].reshape(b, start, kvh, hd)
+        k_pre = _gather_pages(pool["k"], prefix_page_ids)
+        v_pre = _gather_pages(pool["v"], prefix_page_ids)
         k_full = jnp.concatenate([k_pre.astype(k.dtype), k], axis=1)
         v_full = jnp.concatenate([v_pre.astype(v.dtype), v], axis=1)
         key_valid = jnp.concatenate([pre_valid, suf_valid], axis=1)
@@ -662,7 +666,9 @@ def apply_attn_paged_decode(params, x, cfg: ModelConfig, *, pool,
         kernel = cfg.paged_kernel
     if kernel:
         from repro.kernels.paged_attention import paged_attention
-        out = paged_attention(q, new_pool, page_indices, steps, cfg, scale)
+        with jax.named_scope("attention"):
+            out = paged_attention(q, new_pool, page_indices, steps, cfg,
+                                  scale)
     else:
         ck = _gather_pages(new_pool["k"], page_indices)
         cv = _gather_pages(new_pool["v"], page_indices)

@@ -192,6 +192,7 @@ def _ptq_apply(params, x: jnp.ndarray, cfg: QuantConfig) -> jnp.ndarray:
     return y.astype(x.dtype)
 
 
+@jax.named_scope("linear")
 def linear_apply(params: dict[str, Any], x: jnp.ndarray,
                  cfg: QuantConfig = QuantConfig()) -> jnp.ndarray:
     """y = x @ W^T under the configured quantization mode."""

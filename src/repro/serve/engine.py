@@ -73,6 +73,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.attention import CHUNK_THRESHOLD
 from repro.models.model import Model
@@ -121,8 +122,11 @@ class Request:
     shared_pages: int = 0      # prompt pages taken from the prefix trie
     prefill_computed: int = 0  # prompt positions the prefill forward ran
     # -- timeline (perf_counter seconds / engine decode-step counts) ------
+    # t_first / t_done: the end of the step() that produced the first /
+    # last token, when the caller sees it
     t_submit: float = 0.0
     t_admit: float | None = None
+    t_first: float | None = None
     t_done: float | None = None
     submit_step: int = 0
     admit_step: int | None = None
@@ -220,6 +224,7 @@ class ServeEngine:
         self.queue: deque[Request] = deque()
         self.active: dict[int, Request] = {}
         self.finished: list[Request] = []
+        self._seated: list[Request] = []     # first token made this step
         self.step_count = 0
         self._next_rid = 0
         # generation cells: [-1] is current (admission target), earlier
@@ -490,6 +495,7 @@ class ServeEngine:
         req.prefill_computed = L - start
         req.t_admit = time.perf_counter()
         req.admit_step = self.step_count
+        self._seated.append(req)
         self.counters["admitted"] += 1
         self.counters["prefix_hits"] += bool(shared)
         self.counters["pages_shared"] += shared
@@ -531,8 +537,9 @@ class ServeEngine:
                 prefix_page_ids=jnp.asarray(prefix),
                 write_page_ids=jnp.asarray(wp), write_offs=jnp.asarray(wo),
                 write_from=write_from)
-            tok = int(np.asarray(
-                jnp.argmax(logits[:, -1], -1).astype(jnp.int32))[0])
+            with TraceAnnotation("engine.sync"):
+                tok = int(np.asarray(
+                    jnp.argmax(logits[:, -1], -1).astype(jnp.int32))[0])
         self._seat(res, tok)
 
     def _bucket_key(self, res: dict) -> tuple:
@@ -589,8 +596,9 @@ class ServeEngine:
                 suffix_lens=jnp.asarray(slens),
                 write_page_ids=jnp.asarray(wp), write_offs=jnp.asarray(wo),
                 write_pos=jnp.asarray(wpos))
-            toks = np.asarray(jnp.argmax(logits[:, -1], -1)
-                              .astype(jnp.int32))
+            with TraceAnnotation("engine.sync"):
+                toks = np.asarray(jnp.argmax(logits[:, -1], -1)
+                                  .astype(jnp.int32))
         for r, res in enumerate(group):
             self._seat(res, int(toks[r]))
 
@@ -641,43 +649,50 @@ class ServeEngine:
             req.slot = None
         for pid in req.page_ids:
             cell.alloc.decref(pid)    # trie-held pages survive (refcount)
-        req.t_done = time.perf_counter()
         req.done_step = self.step_count
         self.counters["completed"] += 1
         self.finished.append(req)
 
     def _decode_cell(self, cell: _Cell,
-                     packed: list[tuple[int, Request]]) -> None:
-        """One packed decode over ``cell``'s active slots."""
-        self.counters["decode_steps"] += 1
-        for s, req in packed:
-            # this step writes K/V position req.length — grow the
-            # request's table when it crosses a page boundary; the
-            # persistent host arrays only take the per-slot deltas
-            # (_seat/_finish maintain the rest)
-            if req.length // self.page_size >= len(req.page_ids):
-                pid = self._alloc_page(cell)
-                if pid is None:
-                    raise RuntimeError(
-                        f"page pool exhausted ({cell.alloc!r}) — "
-                        f"size n_pages for the slot working set")
-                req.page_ids.append(pid)
-                cell.table[s, len(req.page_ids) - 1] = pid
-            cell.tokens[s, 0] = req.out[-1]
-            cell.steps[s] = req.length
-        batch = {"tokens": cell.tokens, "table": cell.table,
-                 "steps": cell.steps}
-        self._note_trace("decode", ("decode", self.paged_kernel))
-        with self._mesh_ctx():
-            if self.mesh is not None:
-                batch = _place_batch(batch, self.mesh)
-            logits, cell.pool = self._decode(
-                cell.params, cell.pool, jnp.asarray(batch["tokens"]),
-                jnp.asarray(batch["table"]),
-                jnp.asarray(batch["steps"]),
-                kernel=self.paged_kernel)
-            toks = np.asarray(
+                     packed: list[tuple[int, Request]]) -> np.ndarray:
+        """One packed decode over ``cell``'s active slots; returns the
+        (n_slots,) tokens it produced, on the host."""
+        with TraceAnnotation("engine.launch"):
+            self.counters["decode_steps"] += 1
+            for s, req in packed:
+                # this step writes K/V position req.length — grow the
+                # request's table when it crosses a page boundary; the
+                # persistent host arrays only take the per-slot deltas
+                # (_seat/_finish maintain the rest)
+                if req.length // self.page_size >= len(req.page_ids):
+                    pid = self._alloc_page(cell)
+                    if pid is None:
+                        raise RuntimeError(
+                            f"page pool exhausted ({cell.alloc!r}) — "
+                            f"size n_pages for the slot working set")
+                    req.page_ids.append(pid)
+                    cell.table[s, len(req.page_ids) - 1] = pid
+                cell.tokens[s, 0] = req.out[-1]
+                cell.steps[s] = req.length
+            batch = {"tokens": cell.tokens, "table": cell.table,
+                     "steps": cell.steps}
+            self._note_trace("decode", ("decode", self.paged_kernel))
+            with self._mesh_ctx():
+                if self.mesh is not None:
+                    batch = _place_batch(batch, self.mesh)
+                tokens = jnp.asarray(batch["tokens"])
+                table = jnp.asarray(batch["table"])
+                steps = jnp.asarray(batch["steps"])
+                logits, cell.pool = self._decode(
+                    cell.params, cell.pool, tokens, table, steps,
+                    kernel=self.paged_kernel)
+        with TraceAnnotation("engine.sync"), self._mesh_ctx():
+            return np.asarray(
                 jnp.argmax(logits[:, -1], -1).astype(jnp.int32))
+
+    def _take_tokens(self, packed: list[tuple[int, Request]],
+                     toks: np.ndarray) -> None:
+        """Append one decode's tokens; finish requests that are done."""
         done = []
         for s, req in packed:
             tok = int(toks[s])
@@ -703,20 +718,39 @@ class ServeEngine:
         step already run on the new weights, while earlier generations
         keep decoding their in-flight requests in the same call —
         swapping never skips anyone's decode step.
+
+        Each phase runs inside a profiler span (``TraceAnnotation``, a
+        no-op unless a trace is being recorded): ``engine.admit``
+        (reservations, trie, prefill arrays and dispatch),
+        ``engine.launch`` (page growth, slot arrays, their upload and the
+        decode dispatch), ``engine.sync`` (argmax and the host's read of
+        the tokens, also nested in ``engine.admit`` for prefill) and
+        ``engine.retire`` (tokens appended, requests and generations
+        retired).
         """
         n_done = len(self.finished)
+        self._seated = []
         self._apply_staged()
-        self._admit()
+        with TraceAnnotation("engine.admit"):
+            self._admit()
         packed_by_cell = [
             (cell, [(s, self.active[rid])
                     for s, rid in enumerate(cell.slots) if rid is not None])
             for cell in list(self._cells)]
+        decoded = []
         if any(packed for _, packed in packed_by_cell):
             self.step_count += 1
-            for cell, packed in packed_by_cell:
-                if packed:
-                    self._decode_cell(cell, packed)
-        self._retire_cells()
+            decoded = [(packed, self._decode_cell(cell, packed))
+                       for cell, packed in packed_by_cell if packed]
+        with TraceAnnotation("engine.retire"):
+            for packed, toks in decoded:
+                self._take_tokens(packed, toks)
+            self._retire_cells()
+            t = time.perf_counter()
+            for req in self._seated:
+                req.t_first = t
+            for req in self.finished[n_done:]:
+                req.t_done = t
         return self.finished[n_done:]
 
     def run(self, max_steps: int = 100_000) -> list[Request]:
@@ -768,7 +802,7 @@ class ServeEngine:
                 "gen": r.gen,
                 "shared_pages": r.shared_pages,
                 "prefill_computed": r.prefill_computed,
-                "ttft_s": (r.t_admit or r.t_submit) - r.t_submit,
+                "ttft_s": r.t_first - r.t_submit,
                 "latency_s": (r.t_done - r.t_submit) if r.done else None}
                for r in reqs]
         total_tokens = sum(len(r.out) for r in reqs)

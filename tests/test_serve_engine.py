@@ -477,6 +477,69 @@ def test_paged_kernel_engine_ragged_identity(fp_cell):
     assert toks[False] == toks[True]
 
 
+# -- profiler spans and the request timeline -------------------------------
+
+class _SpanLog:
+    """Stands in for ``TraceAnnotation``: logs (depth, name) on entry."""
+    log: list = []
+    depth = 0
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _SpanLog.log.append((_SpanLog.depth, self.name))
+        _SpanLog.depth += 1
+
+    def __exit__(self, *exc):
+        _SpanLog.depth -= 1
+
+
+@pytest.mark.parametrize("bucketed", [True, False])
+def test_step_phase_spans_in_order(fp_cell, monkeypatch, bucketed):
+    """A step() with an arrival: admission (its prefill read back inside
+    it), then the decode launch, its read-back and retirement, each in
+    its own span and none at the top of another."""
+    import repro.serve.engine as engine_mod
+    model, params = fp_cell
+    eng = ServeEngine(model, params, n_slots=2, max_len=16, page_size=4,
+                      bucket_prefill=bucketed)
+    eng.submit(_prompts(model.cfg, plen=5, n=1)[0], 3)
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", _SpanLog)
+    monkeypatch.setattr(_SpanLog, "log", [])
+    eng.step()
+    assert _SpanLog.log == [(0, "engine.admit"), (1, "engine.sync"),
+                            (0, "engine.launch"), (0, "engine.sync"),
+                            (0, "engine.retire")]
+    _SpanLog.log.clear()
+    eng.step()                                 # no arrival: no admission
+    assert [n for _, n in _SpanLog.log] == [
+        "engine.admit", "engine.launch", "engine.sync", "engine.retire"]
+    assert _SpanLog.depth == 0
+
+
+def test_request_timeline_first_token(fp_cell):
+    """``t_first`` is stamped at the end of the step() that made the first
+    token, and ``report()`` times TTFT to it; a request done at prefill
+    has its first and last token at the same step end."""
+    model, params = fp_cell
+    eng = ServeEngine(model, params, n_slots=2, max_len=16, page_size=4)
+    prompts = _prompts(model.cfg, plen=5, n=3, seed=11)
+    eng.submit(prompts[0], 4)
+    eng.submit(prompts[1], 1)                  # done at its prefill
+    eng.step()
+    (one,) = eng.finished
+    assert one.t_first == one.t_done and one.t_admit <= one.t_first
+    eng.submit(prompts[2], 2)
+    done = eng.run()
+    reqs = sorted([one] + done, key=lambda r: r.rid)
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done
+    assert reqs[0].t_first < reqs[0].t_done    # 4 tokens over 4 steps
+    ttft = {x["rid"]: x["ttft_s"] for x in eng.report()["requests"]}
+    assert all(ttft[r.rid] == r.t_first - r.t_submit for r in reqs)
+
+
 # -- bench contract ----------------------------------------------------------
 
 def test_serve_engine_bench_emits_tokens_per_s(cache):

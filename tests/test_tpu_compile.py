@@ -13,6 +13,7 @@ import this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from repro.models.model import Model
 HBM_BYTES = 16 * 2**30          # one v5e chip
 SLOTS, MAX_LEN, PAGE = 8, 2048, 16
 PREFILL_ROWS, PREFILL_LEN = 8, 256
+PREFIX_PAGES = 4
 
 
 @pytest.fixture(scope="module")
@@ -82,27 +84,54 @@ def _check_fits(compiled):
     assert 0 < used < HBM_BYTES, used
 
 
-def test_paged_decode_compiles_for_v5e(served):
-    """The packed decode ``ServeEngine`` jits: gather path, ``int_dot``,
-    8 slots x 2048 positions, pool donated."""
-    model, params, pool, i32 = served
-    decode = jax.jit(model.decode_step_paged, static_argnames=("kernel",),
-                     donate_argnums=(1,))
-    compiled = decode.lower(params, pool, i32(SLOTS, 1),
-                            i32(SLOTS, MAX_LEN // PAGE), i32(SLOTS),
-                            kernel=False).compile()
-    _check_fits(compiled)
-
-
-def test_bucketed_prefill_compiles_for_v5e(served):
-    """One bucketed batched-prefill program: 8 rows x 256 positions, no
-    shared prefix, pool donated."""
+def _prefill(served, n_pre: int):
+    """One bucketed batched-prefill program, 8 rows x 256 positions
+    behind ``n_pre`` shared prefix pages, pool donated."""
     model, params, pool, i32 = served
     prefill = jax.jit(model.prefill_paged_batched, donate_argnums=(2,))
     rows, lb = PREFILL_ROWS, PREFILL_LEN
-    compiled = prefill.lower(
-        params, i32(rows, lb), pool, prefix_page_ids=i32(rows, 0),
+    return prefill.lower(
+        params, i32(rows, lb), pool, prefix_page_ids=i32(rows, n_pre),
         prefix_lens=i32(rows), suffix_lens=i32(rows),
         write_page_ids=i32(rows, lb), write_offs=i32(rows, lb),
         write_pos=i32(rows, lb)).compile()
-    _check_fits(compiled)
+
+
+@pytest.fixture(scope="module")
+def compiled(served):
+    """Each served program compiled once for the tests below."""
+    model, params, pool, i32 = served
+    decode = jax.jit(model.decode_step_paged, static_argnames=("kernel",),
+                     donate_argnums=(1,))
+    return {"decode": decode.lower(params, pool, i32(SLOTS, 1),
+                                   i32(SLOTS, MAX_LEN // PAGE), i32(SLOTS),
+                                   kernel=False).compile(),
+            "prefill": _prefill(served, 0),
+            "prefill_shared": _prefill(served, PREFIX_PAGES)}
+
+
+def test_paged_decode_compiles_for_v5e(compiled):
+    """The packed decode ``ServeEngine`` jits: gather path, ``int_dot``,
+    8 slots x 2048 positions, pool donated."""
+    _check_fits(compiled["decode"])
+
+
+def test_bucketed_prefill_compiles_for_v5e(compiled):
+    """One bucketed batched-prefill program: 8 rows x 256 positions, no
+    shared prefix, pool donated."""
+    _check_fits(compiled["prefill"])
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("decode", {"linear", "kv_gather", "attention"}),
+    # a KV8 pool recomputes shared prefixes, so its prefill gathers none
+    ("prefill", {"linear", "attention"}),
+    # the bucket an exact-pool engine sends behind a shared prefix
+    ("prefill_shared", {"linear", "kv_gather", "attention"}),
+], ids=["decode", "prefill", "prefill_shared"])
+def test_served_programs_carry_layer_scopes(compiled, program, scopes):
+    """The layer scopes reach the compiled program's instructions, whose
+    ``op_name`` a device trace reports as each op's ``tf_op``."""
+    names = re.findall(r'op_name="([^"]*)"', compiled[program].as_text())
+    found = {part for n in names for part in n.split("/")}
+    assert found & {"linear", "kv_gather", "attention"} == scopes
