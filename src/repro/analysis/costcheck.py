@@ -55,9 +55,9 @@ GATHER_PRIMS = frozenset({"gather", "dynamic_slice"})
 # single-operand structural transforms: the output is still "the same
 # buffer" for the purposes of pool-read attribution (view tracking)
 VIEW_PRIMS = frozenset({"reshape", "transpose", "convert_element_type",
-                        "squeeze", "broadcast_in_dim", "slice", "rev",
-                        "copy", "dynamic_update_slice", "copy_p",
-                        *SCATTER_PRIMS})
+                        "bitcast_convert_type", "squeeze",
+                        "broadcast_in_dim", "slice", "rev", "copy",
+                        "dynamic_update_slice", "copy_p", *SCATTER_PRIMS})
 
 
 @dataclasses.dataclass
@@ -155,7 +155,10 @@ def _walk(jaxpr: Any, view_in: list[bool], weight: float, in_loop: bool,
             for v, t in zip(eqn.outvars, out_view):
                 if t:
                     views.add(v)
-        if not entered and name in VIEW_PRIMS and inv and inv[0]:
+        # a combining scatter (add, mul, ...) carries its combiner as a
+        # sub-jaxpr; its result is still the operand's buffer
+        if (name in VIEW_PRIMS and inv and inv[0]
+                and (not entered or name in SCATTER_PRIMS)):
             for v in eqn.outvars:
                 views.add(v)
     return [(not isinstance(v, Literal)) and v in views

@@ -71,25 +71,26 @@ def _quantize_rows(x, qmax):
 
 
 def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
-                   ps: int, scale: float):
+                   ps: int, scale: float, unpack):
     """One slot: cond-guarded live-page walk -> full-extent softmax ->
     cond-guarded live-page P·V accumulation."""
     refs = list(refs)
-    table_ref, steps_ref, q_ref = refs[:3]
-    refs = refs[3:]
+    table_ref, steps_ref, layer_ref, q_ref = refs[:4]
+    refs = refs[4:]
     sq_ref = None
     if quant:
         sq_ref, refs = refs[0], refs[1:]
-    kpool_ref, vpool_ref = refs[:2]
-    refs = refs[2:]
-    kspool_ref = vspool_ref = None
-    if int8_pool:
-        (kspool_ref, vspool_ref), refs = refs[:2], refs[2:]
-    qmax_ref, out_ref = refs
+    pool_ref, qmax_ref, out_ref = refs
     qmax = qmax_ref[...]                              # (1,) f32: 127.0
 
     qh = q_ref[0]                                     # (KV, G, hd)
     kv, g, hd = qh.shape
+    layer = layer_ref[0]
+
+    def page(name, pid):
+        """Segment ``name`` of page ``pid`` of this layer: (ps, KV, w)."""
+        return unpack(pool_ref[layer, pid], names=(name,))[name]
+
     step = steps_ref[0]
     n_live = step // ps + 1                           # pages holding rows
     sq = sq_ref[0] if quant else None                 # (KV, G, 1)
@@ -97,10 +98,10 @@ def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
 
     # ---- phase 1: per-page score tiles (+ per-page V metadata) ----------
     def score_tile(pid):
-        kpage = kpool_ref[pid]                        # (ps, KV, hd)
+        kpage = page("k", pid)                        # (ps, KV, hd)
         if quant:
             if int8_pool:
-                kk, sks = kpage, kspool_ref[pid]      # stored f32 scales
+                kk, sks = kpage, page("ks", pid)      # stored f32 scales
             else:
                 kk, sks = _quantize_rows(kpage, qmax)  # pool-dtype scales
             s32 = jnp.einsum("kgd,skd->kgs", qh, kk,
@@ -108,7 +109,7 @@ def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
             sk_b = sks[..., 0].T[:, None, :]          # (KV, 1, ps)
             return s32.astype(jnp.float32) * scale * sq * sk_b
         if int8_pool:
-            kf = kpage.astype(jnp.float32) * kspool_ref[pid]
+            kf = kpage.astype(jnp.float32) * page("ks", pid)
             return jnp.einsum("kgd,skd->kgs", qh, kf) * scale
         return jnp.einsum("kgd,skd->kgs", qh, kpage) \
             .astype(jnp.float32) * scale
@@ -119,9 +120,9 @@ def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
         (dynamic re-quantization). Dead table entries point at the null
         page (pid 0), matching what the oracle's gather would read."""
         if quant and int8_pool:
-            return vspool_ref[pid][..., 0].T           # (KV, ps)
+            return page("vs", pid)[..., 0].T           # (KV, ps)
         if quant:
-            return jnp.max(jnp.abs(vpool_ref[pid]), axis=0)   # (KV, hd)
+            return jnp.max(jnp.abs(page("v", pid)), axis=0)   # (KV, hd)
         return None
 
     # the page walks are lax.scans over the (static) page axis, not
@@ -150,7 +151,7 @@ def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
         vmeta = jnp.transpose(vs_pages, (1, 0, 2)) \
             .reshape(kv, s_full)                       # (KV, S)
     elif quant:
-        vmax0 = jnp.full((kv, hd), -jnp.inf, vpool_ref.dtype)
+        vmax0 = jnp.full((kv, hd), -jnp.inf, pool_ref.dtype)
         vmax, tiles = jax.lax.scan(tile_step, vmax0, idx)
     else:
         _, tiles = jax.lax.scan(tile_step, None, idx)
@@ -185,7 +186,7 @@ def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
         qp, sps = _quantize_rows(p * vs_b, qmax)
         o32 = walk(jnp.zeros((kv, g, hd), jnp.int32),
                    lambda pid, j: jnp.einsum(
-                       "kgs,skd->kgd", ptile(qp, j), vpool_ref[pid],
+                       "kgs,skd->kgd", ptile(qp, j), page("v", pid),
                        preferred_element_type=jnp.int32))
         out_ref[0] = o32.astype(jnp.float32) * sps
     elif quant:
@@ -195,7 +196,7 @@ def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
         sv = vmax / qmax.astype(vmax.dtype)[0] + 1e-8  # (KV, hd), pool dtype
 
         def pv(pid, j):
-            qv = jnp.clip(jnp.round(vpool_ref[pid] / sv),
+            qv = jnp.clip(jnp.round(page("v", pid) / sv),
                           -128, 127).astype(jnp.int8)
             return jnp.einsum("kgs,skd->kgd", ptile(qp, j), qv,
                               preferred_element_type=jnp.int32)
@@ -206,42 +207,47 @@ def _decode_kernel(*refs, quant: bool, int8_pool: bool, pages: int,
             jnp.zeros((kv, g, hd), jnp.float32),
             lambda pid, j: jnp.einsum(
                 "kgs,skd->kgd", ptile(p, j),
-                vpool_ref[pid].astype(jnp.float32) * vspool_ref[pid]))
+                page("v", pid).astype(jnp.float32) * page("vs", pid)))
     else:
-        pc = p.astype(vpool_ref.dtype)
+        pc = p.astype(pool_ref.dtype)
         out_ref[0] = walk(
             jnp.zeros((kv, g, hd), jnp.float32),
             lambda pid, j: jnp.einsum(
-                "kgs,skd->kgd", ptile(pc, j), vpool_ref[pid],
+                "kgs,skd->kgd", ptile(pc, j), page("v", pid),
                 preferred_element_type=jnp.float32))
 
 
-def paged_attention(q, pool, page_indices, steps, cfg, scale, *,
-                    interpret: bool | None = None):
+def paged_attention(q, pool, layer, page_indices, steps, cfg, scale, *,
+                    unpack, interpret: bool | None = None):
     """Live-page decode attention. ``q`` (B, 1, H, hd) post-RoPE;
-    ``pool`` one layer's page-pool leaves (n_pages, ps, KV, hd) (+ scale
-    leaves under KV8); ``page_indices`` (B, P) int32; ``steps`` (B,)
-    int32 — the position written this step. Returns (B, 1, H, hd) in the
-    dtype ``attend_cached`` would produce for the same layout."""
+    ``pool`` the layer-stacked page-pool leaf (L, n_pages, page_elems),
+    read at layer ``layer`` (a traced int32 scalar); ``unpack(pages,
+    names=...)`` splits (..., page_elems) pages into ``{name: (...,
+    page_size, KV, width)}`` segments ("k", "v", + "ks", "vs" under KV8);
+    ``page_indices`` (B, P) int32; ``steps`` (B,) int32 — the position
+    written this step. Returns (B, 1, H, hd) in the dtype
+    ``attend_cached`` would produce for the same layout."""
     if interpret is None:
         from repro.kernels import ops
         interpret = ops.default_interpret()
     b, sq_len, h, hd = q.shape
     if sq_len != 1:
         raise ValueError(f"decode kernel expects Sq == 1, got {sq_len}")
-    ps, kvh = pool["k"].shape[1], pool["k"].shape[2]
+    kvh = cfg.n_kv_heads
+    segs = jax.eval_shape(unpack, jax.ShapeDtypeStruct(pool.shape[-1:],
+                                                       pool.dtype))
+    ps = segs["k"].shape[0]
     g = h // kvh
     pages = page_indices.shape[1]
     quant = cfg.quant_attention
-    int8_pool = pool["k"].dtype == jnp.int8
+    int8_pool = "ks" in segs
     qg = q.reshape(b, kvh, g, hd)
 
-    def full(a):
-        return pl.BlockSpec(a.shape, lambda i, nd=a.ndim: (0,) * nd)
-
-    inputs = [page_indices.astype(jnp.int32), steps.astype(jnp.int32)]
+    inputs = [page_indices.astype(jnp.int32), steps.astype(jnp.int32),
+              jnp.reshape(layer, (1,)).astype(jnp.int32)]
     in_specs = [pl.BlockSpec((1, pages), lambda i: (i, 0)),
-                pl.BlockSpec((1,), lambda i: (i,))]
+                pl.BlockSpec((1,), lambda i: (i,)),
+                pl.BlockSpec((1,), lambda i: (0,))]
     qspec = pl.BlockSpec((1, kvh, g, hd), lambda i: (i, 0, 0, 0))
     if quant:
         qq, sqs = quantize_per_token(qg)       # pool-dtype scale, like the
@@ -252,10 +258,8 @@ def paged_attention(q, pool, page_indices, steps, cfg, scale, *,
         # the int8-pool float path contracts q in f32 (oracle casts)
         inputs.append(qg.astype(jnp.float32) if int8_pool else qg)
         in_specs.append(qspec)
-    names = ("k", "v", "ks", "vs") if int8_pool else ("k", "v")
-    for name in names:
-        inputs.append(pool[name])
-        in_specs.append(full(pool[name]))
+    inputs.append(pool)
+    in_specs.append(pl.BlockSpec(pool.shape, lambda i: (0, 0, 0)))
     # 127.0 as a runtime operand: a literal denominator would let the
     # interpret-mode compiler fold the quantizer divisions into reciprocal
     # multiplies, 1 ulp off the oracle's exact division
@@ -265,7 +269,7 @@ def paged_attention(q, pool, page_indices, steps, cfg, scale, *,
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, quant=quant, int8_pool=int8_pool,
-                          pages=pages, ps=ps, scale=scale),
+                          pages=pages, ps=ps, scale=scale, unpack=unpack),
         grid=(b,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kvh, g, hd), lambda i: (i, 0, 0, 0)),
@@ -274,5 +278,5 @@ def paged_attention(q, pool, page_indices, steps, cfg, scale, *,
     )(*inputs)
     out = out.reshape(b, 1, h, hd)
     if not quant and not int8_pool:
-        out = out.astype(pool["v"].dtype)      # the oracle's bf16 P·V dot
+        out = out.astype(pool.dtype)           # the oracle's bf16 P·V dot
     return out

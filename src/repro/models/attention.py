@@ -7,6 +7,9 @@ on TPU).
 """
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -389,37 +392,172 @@ def apply_attn(params, x, cfg: ModelConfig, *, positions, cache=None,
 # Paged KV cache (the continuous-batching serve path, repro.serve)
 # --------------------------------------------------------------------------
 #
-# The pool is a static-shape pytree: (n_pages, page_size, KV, D) K/V buffers
-# (+ per-position scales under KV8) shared by every slot, addressed through
-# an int32 page table — the same static-gather trick DevicePlan uses for
-# forest schedules, so decode is one fixed-shape jit regardless of which
-# requests occupy which slots. Logical position p of a slot lives at
-# (page_indices[slot, p // page_size], p % page_size); page 0 is the null
-# page (never allocated — inactive slots point at it, masked writes land
-# in it).
+# The pool holds every slot's K/V (+ per-position scales under KV8) in
+# pages addressed through an int32 page table — the same static-gather
+# trick DevicePlan uses for forest schedules, so decode is one fixed-shape
+# jit regardless of which requests occupy which slots. Logical position p
+# of a slot lives at row p % page_size of page page_indices[slot, p //
+# page_size]; page 0 is the null page (never allocated — inactive slots
+# point at it; writes aimed at it are dropped, so it stays zeros).
+#
+# One attention layer's pool is ONE leaf (n_pages, page_elems): a page is
+# one row of the leaf, its segments side by side — the page_size K rows,
+# then the V rows (KV x D each), then under KV8 the K and V scale rows
+# (KV f32 each, kept as their int8 bytes). The bytes of each position are
+# the ones the unfolded (n_pages, page_size, KV, D) pool held. Model
+# stacks the repeats in front and carries the stack through its layer
+# scan; each layer writes its new rows into the stacked leaf at [layer,
+# page] and gathers its pages from it. The shape is for the TPU:
+#   * a page row is 6528 int8 lanes on smollm-135m, 8448 on chatglm3-6b —
+#     whole 128-lane tiles, so the leaf keeps plain row-major tiles. A
+#     (KV, D) minor tile such as (3, 64) int8, or 48 f32 scales a page,
+#     the compiler stores pages-minor, and then relays out the layer's
+#     slice around every scatter and gather: whole-pool copies per layer
+#     per step;
+#   * the page count is rounded up to PAGE_TILE, whole int8 sublane tiles,
+#     so a scatter's (layer, page) index flattens without a copy;
+#   * a row lies inside a page row, where the TPU scatters only whole
+#     minor windows (a narrower window becomes a loop, one row an
+#     iteration): a write zeroes the lanes of its rows and adds the rows
+#     in, two full-page scatters on the leaf's integer view, exact for
+#     any bytes. Decode writes one page a slot; a prefill writes each
+#     page once with all of its rows, as the scatter's cost goes by
+#     update, not by byte.
+PAGE_TILE = 32
+
+
+def pool_layout(cfg: ModelConfig) -> tuple:
+    """The segments of a pool page, in order: ``(name, dtype, width)``,
+    each ``page_size`` rows of ``(KV, width)``. The leaf's dtype is the
+    first segment's; a segment of another dtype is kept as its bytes."""
+    hd = cfg.hd
+    if cfg.kv_cache_bits == 8:
+        return (("k", jnp.int8, hd), ("v", jnp.int8, hd),
+                ("ks", jnp.float32, 1), ("vs", jnp.float32, 1))
+    return (("k", cfg.dtype, hd), ("v", cfg.dtype, hd))
+
+
+def _bitcast(a, dtype):
+    """``a``'s bytes as ``dtype`` (no op when it already is one)."""
+    if a.dtype == jnp.dtype(dtype):
+        return a
+    return jax.lax.bitcast_convert_type(a, dtype)
+
+
+def _int_view(a):
+    """``a`` as the signed integer type of its width."""
+    return _bitcast(a, jnp.dtype(f"int{8 * a.dtype.itemsize}"))
+
+
+def _spans(layout, kvh: int):
+    """(name, dtype, width, row elements in the leaf's dtype) a segment."""
+    leaf = jnp.dtype(layout[0][1]).itemsize
+    return [(name, dt, w, kvh * w * jnp.dtype(dt).itemsize // leaf)
+            for name, dt, w in layout]
+
+
+def page_rows(layout, kvh: int, page_elems: int) -> int:
+    """Rows (positions) a page of ``page_elems`` leaf elements holds."""
+    return page_elems // sum(r for *_, r in _spans(layout, kvh))
+
+
+def unpack_pages(pages, layout, kvh: int, names=None) -> dict:
+    """Split (..., page_elems) pages into ``{name: (..., page_size, KV,
+    width)}`` views in each segment's dtype (``names``: only those)."""
+    ps = page_rows(layout, kvh, pages.shape[-1])
+    lead = pages.shape[:-1]
+    out, start = {}, 0
+    for name, dt, w, row in _spans(layout, kvh):
+        seg = pages[..., start:start + ps * row]
+        start += ps * row
+        if names is not None and name not in names:
+            continue
+        if jnp.dtype(dt) != pages.dtype:
+            seg = _bitcast(seg.reshape(lead + (ps * kvh * w, -1)), dt)
+        out[name] = seg.reshape(lead + (ps, kvh, w))
+    return out
+
+
+def _page_size(pool, cfg: ModelConfig) -> int:
+    return page_rows(pool_layout(cfg), cfg.n_kv_heads, pool["kv"].shape[-1])
+
 
 def init_attn_page_pool(cfg: ModelConfig, n_pages: int, page_size: int):
-    """One attention layer's page pool (unstacked; Model stacks repeats)."""
-    shape = (n_pages, page_size, cfg.n_kv_heads, cfg.hd)
-    if cfg.kv_cache_bits == 8:
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "ks": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-                "vs": jnp.zeros(shape[:-1] + (1,), jnp.float32)}
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype)}
+    """One attention layer's page pool (unstacked; Model stacks repeats):
+    ``{"kv": (n_pages rounded up to PAGE_TILE, page_elems)}``."""
+    layout = pool_layout(cfg)
+    elems = page_size * sum(r for *_, r in _spans(layout, cfg.n_kv_heads))
+    n = -(-n_pages // PAGE_TILE) * PAGE_TILE
+    return {"kv": jnp.zeros((n, elems), layout[0][1])}
+
+
+def _store_rows(pool, layer, page, off, rows: dict, cfg: ModelConfig,
+                group: int = 1):
+    """``rows[name]`` (..., KV, width) written at row ``off`` of page
+    ``page`` of layer ``layer`` of the stacked leaf, for every segment at
+    once; ``page`` / ``off`` have the rows' leading shape, flattened to
+    lanes. Lanes go by runs of ``group``: a run writes one page, its first
+    lane's, and a lane of the run that names another page is not written
+    (the prefills' lanes run through whole pages from a page boundary, so
+    ``group=page_size`` holds each run to one page; decode's slots each
+    name their own page, ``group=1``). Rows aimed at the null page are
+    dropped. Two full-page scatters a run, straight into the carried leaf
+    (see the section comment): one zeroes the rows' lanes, one adds the
+    rows. Returns the new pool."""
+    buf = pool["kv"]
+    layout = pool_layout(cfg)
+    kvh = cfg.n_kv_heads
+    ps = page_rows(layout, kvh, buf.shape[-1])
+    page, off = page.reshape(-1), off.reshape(-1)
+    pad = -page.shape[0] % group
+    page, off = jnp.pad(page, (0, pad)), jnp.pad(off, (0, pad))
+    ng = page.shape[0] // group
+    lanes = page.reshape(ng, group)
+    first = lanes[:, 0]                                         # (ng,)
+    live = (lanes == first[:, None]) & (first != 0)[:, None]
+    onehot = (off.reshape(ng, group)[:, :, None] == jnp.arange(ps)) \
+        & live[:, :, None]                                      # (ng, G, ps)
+    slot = onehot.any(axis=1)                                   # (ng, ps)
+    src = jnp.argmax(onehot, axis=1)[:, :, None]    # the lane of each slot
+    vals, hits = [], []
+    for name, dt, w, row in _spans(layout, kvh):
+        r = rows[name].reshape(-1, kvh * w).astype(dt)
+        r = _bitcast(r, buf.dtype).reshape(-1, row)
+        r = jnp.pad(r, ((0, pad), (0, 0))).reshape(ng, group, row)
+        r = (jnp.broadcast_to(r, (ng, ps, row)) if group == 1
+             else jnp.take_along_axis(r, src, axis=1))          # (ng, ps, row)
+        vals.append(r.reshape(ng, ps * row))
+        hits.append(jnp.broadcast_to(slot[:, :, None], (ng, ps, row))
+                    .reshape(ng, ps * row))
+    hit = jnp.concatenate(hits, -1)
+    val = _int_view(jnp.concatenate(vals, -1))
+    ibuf = _int_view(buf)
+    idx = jnp.stack([jnp.broadcast_to(layer, first.shape), first], -1)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(0, 1),
+        scatter_dims_to_operand_dims=(0, 1))
+    mode = jax.lax.GatherScatterMode.FILL_OR_DROP
+    ibuf = jax.lax.scatter_mul(ibuf, idx, (~hit).astype(ibuf.dtype),
+                               dnums, mode=mode)
+    ibuf = jax.lax.scatter_add(ibuf, idx, jnp.where(hit, val, 0), dnums,
+                               mode=mode)
+    return {"kv": _bitcast(ibuf, buf.dtype)}
 
 
 @jax.named_scope("kv_gather")
-def _gather_pages(buf, page_indices):
-    """(n_pages, ps, ...) gathered to a contiguous (B, P*ps, ...) view in
-    logical-position order — position p of slot b lands at index p, so the
-    downstream attention sees exactly the layout the dense cache has."""
-    g = buf[page_indices]                       # (B, P, ps, ...)
-    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
+def _gather_pages(pool, layer, page_indices, cfg: ModelConfig, names):
+    """Layer ``layer``'s pages gathered straight from the stacked leaf,
+    each named segment as a contiguous (B, P*ps, KV, width) view in
+    logical-position order — position p of slot b lands at index p, so
+    the downstream attention sees exactly the layout the dense cache
+    has."""
+    g = pool["kv"][layer, page_indices]         # (B, P, page_elems)
+    views = unpack_pages(g, pool_layout(cfg), cfg.n_kv_heads, names)
+    b = page_indices.shape[0]
+    return [views[n].reshape((b, -1) + views[n].shape[-2:]) for n in names]
 
 
-def apply_attn_paged_prefill(params, x, cfg: ModelConfig, *, pool,
+def apply_attn_paged_prefill(params, x, cfg: ModelConfig, *, pool, layer,
                              prefix_page_ids, write_page_ids, write_offs,
                              write_from: int):
     """Suffix prefill for ONE request (B=1) against a page pool.
@@ -432,7 +570,11 @@ def apply_attn_paged_prefill(params, x, cfg: ModelConfig, *, pool,
     computation for exact (non-KV8) pools. Suffix K/V for positions
     start+write_from..L-1 are written to ``(write_page_ids[i],
     write_offs[i])`` (``write_from`` > 0 lets a KV8 full-recompute skip
-    re-writing pages it shares). Returns (out, new_pool).
+    re-writing pages it shares). The writes start at a page boundary and
+    run through whole pages: each run of ``page_size`` writes names one
+    page (the last run may stop short). ``pool`` is the layer-stacked
+    pool (:func:`init_attn_page_pool`) and ``layer`` the traced layer
+    index its rows are written and read at. Returns (out, new_pool).
 
     All lengths and index-array shapes are static: the jit retraces per
     (suffix_len, n_prefix_pages) pair — decode, by contrast, is a single
@@ -442,7 +584,7 @@ def apply_attn_paged_prefill(params, x, cfg: ModelConfig, *, pool,
     qcfg = cfg.quant
     b, ls, _ = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    ps = pool["k"].shape[1]
+    ps = _page_size(pool, cfg)
     n_pre = len(prefix_page_ids)
     start = n_pre * ps
     total = start + ls
@@ -463,23 +605,23 @@ def apply_attn_paged_prefill(params, x, cfg: ModelConfig, *, pool,
 
     # write the suffix K/V into this request's (private) pages — same
     # quantization as the dense prefill cache write
-    int8_pool = pool["k"].dtype == jnp.int8
-    new_pool = dict(pool)
-    if int8_pool:
+    if cfg.kv_cache_bits == 8:
         qk, ks = _quantize_kv(k)
         qv, vs = _quantize_kv(v)
         stores = {"k": qk, "v": qv, "ks": ks, "vs": vs}
     else:
         stores = {"k": k, "v": v}
-    for name, val in stores.items():
-        rows = val[0, write_from:].astype(pool[name].dtype)
-        new_pool[name] = pool[name].at[write_page_ids, write_offs].set(rows)
+    new_pool = _store_rows(pool, layer, write_page_ids, write_offs,
+                           {n: val[0, write_from:]
+                            for n, val in stores.items()}, cfg, ps)
 
     # full K/V view: gathered shared prefix (exact working-dtype pools
-    # only — the engine guarantees n_pre == 0 for KV8) + in-pass suffix
+    # only — the engine guarantees n_pre == 0 for KV8) + in-pass suffix.
+    # The prefix pages are never written here, so they are read from the
+    # updated leaf, which the scan then carries on without a copy.
     if n_pre:
-        k_pre = _gather_pages(pool["k"], prefix_page_ids[None])
-        v_pre = _gather_pages(pool["v"], prefix_page_ids[None])
+        k_pre, v_pre = _gather_pages(new_pool, layer, prefix_page_ids[None],
+                                     cfg, ("k", "v"))
         k_full = jnp.concatenate([k_pre.astype(k.dtype), k], axis=1)
         v_full = jnp.concatenate([v_pre.astype(v.dtype), v], axis=1)
     else:
@@ -504,7 +646,7 @@ def apply_attn_paged_prefill(params, x, cfg: ModelConfig, *, pool,
 
 
 def apply_attn_paged_prefill_batched(params, x, cfg: ModelConfig, *, pool,
-                                     prefix_page_ids, prefix_lens,
+                                     layer, prefix_page_ids, prefix_lens,
                                      suffix_lens, write_page_ids, write_offs,
                                      write_pos):
     """Bucket-padded batched prefill: N requests' suffixes in ONE call.
@@ -516,7 +658,10 @@ def apply_attn_paged_prefill_batched(params, x, cfg: ModelConfig, *, pool,
     multiple of page_size) counts the row's real shared positions.
     Suffix K/V rows are written through ``(write_page_ids, write_offs)``
     (B, Lb) — ``write_pos[b, i]`` names the suffix row stored by write i,
-    and dead write lanes target the null page. Returns (out, new_pool).
+    and dead write lanes target the null page. A row's writes start at a
+    page boundary and run through whole pages, as in
+    :func:`apply_attn_paged_prefill`. ``pool`` / ``layer`` as there.
+    Returns (out, new_pool).
 
     Parity with the per-request path is per-row exact: positions, masks
     and stored bytes match :func:`apply_attn_paged_prefill` for every
@@ -530,7 +675,7 @@ def apply_attn_paged_prefill_batched(params, x, cfg: ModelConfig, *, pool,
     qcfg = cfg.quant
     b, ls, _ = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    ps = pool["k"].shape[1]
+    ps = _page_size(pool, cfg)
     n_pre = prefix_page_ids.shape[1]
     start = n_pre * ps
     total = start + ls
@@ -556,30 +701,29 @@ def apply_attn_paged_prefill_batched(params, x, cfg: ModelConfig, *, pool,
     # scatter each row's suffix K/V through its write lanes; lane i stores
     # suffix row write_pos[b, i] (rows, not a slice, so KV8 full-recompute
     # rows can skip re-writing shared pages); dead lanes hit the null page
-    int8_pool = pool["k"].dtype == jnp.int8
-    new_pool = dict(pool)
-    if int8_pool:
+    if cfg.kv_cache_bits == 8:
         qk, ks = _quantize_kv(k)
         qv, vs = _quantize_kv(v)
         stores = {"k": qk, "v": qv, "ks": ks, "vs": vs}
     else:
         stores = {"k": k, "v": v}
-    for name, val in stores.items():
-        rows = jnp.take_along_axis(
-            val, write_pos[:, :, None, None], axis=1)           # (B, Lb, ...)
-        new_pool[name] = pool[name].at[write_page_ids, write_offs].set(
-            rows.astype(pool[name].dtype))
+    new_pool = _store_rows(
+        pool, layer, write_page_ids, write_offs,
+        {n: jnp.take_along_axis(val, write_pos[:, :, None, None], axis=1)
+         for n, val in stores.items()}, cfg, math.gcd(ps, ls))  # (B, Lb, ...)
 
     # full K/V view per row: gathered shared prefix (exact pools only —
     # the engine guarantees prefix_lens == 0 for KV8) + in-pass suffix.
     # Padded lanes are zeroed: masked out of the scores anyway, but the
-    # quant-attention PV absmax must not see gathered/padded garbage.
+    # quant-attention PV absmax must not see gathered/padded garbage. The
+    # engine never batches a row with the writer of its prefix pages, so
+    # real prefix lanes read the same bytes from the updated leaf.
     suf_idx = jnp.arange(ls)
     suf_valid = suf_idx[None, :] < suffix_lens[:, None]         # (B, Lb)
     if n_pre:
         pre_valid = jnp.arange(start)[None, :] < prefix_lens[:, None]
-        k_pre = _gather_pages(pool["k"], prefix_page_ids)
-        v_pre = _gather_pages(pool["v"], prefix_page_ids)
+        k_pre, v_pre = _gather_pages(new_pool, layer, prefix_page_ids, cfg,
+                                     ("k", "v"))
         k_full = jnp.concatenate([k_pre.astype(k.dtype), k], axis=1)
         v_full = jnp.concatenate([v_pre.astype(v.dtype), v], axis=1)
         key_valid = jnp.concatenate([pre_valid, suf_valid], axis=1)
@@ -608,16 +752,18 @@ def apply_attn_paged_prefill_batched(params, x, cfg: ModelConfig, *, pool,
     return y.astype(x.dtype), new_pool
 
 
-def apply_attn_paged_decode(params, x, cfg: ModelConfig, *, pool,
+def apply_attn_paged_decode(params, x, cfg: ModelConfig, *, pool, layer,
                             page_indices, steps, kernel: bool | None = None):
     """One paged decode step over all slots. x (B, 1, d); page_indices
     (B, P) int32; steps (B,) int32 — the logical position the new token is
-    written at (== tokens held so far). Returns (out, new_pool).
+    written at (== tokens held so far). ``pool`` / ``layer`` as in
+    :func:`apply_attn_paged_prefill`. Returns (out, new_pool).
 
     Inactive slots carry a page table of null pages (page 0) and step 0:
-    their writes land in the null page and their rows are garbage the
-    scheduler never reads — the shapes never change, so decode re-traces
-    exactly once per engine regardless of arrivals/evictions.
+    their writes aim at the null page and are dropped, and their rows are
+    garbage the scheduler never reads — the shapes never change, so
+    decode re-traces exactly once per engine regardless of
+    arrivals/evictions.
 
     ``kernel`` (default ``cfg.paged_kernel``) routes attention through the
     Pallas live-page kernel (:mod:`repro.kernels.paged_attention`), which
@@ -629,7 +775,7 @@ def apply_attn_paged_decode(params, x, cfg: ModelConfig, *, pool,
     qcfg = cfg.quant
     b, sq, _ = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    ps = pool["k"].shape[1]
+    ps = _page_size(pool, cfg)
     xn = rms_norm(x, params["norm"], cfg.norm_eps)
     q = linear_apply(params["wq"], xn, qcfg).reshape(b, sq, h, hd)
     k = linear_apply(params["wk"], xn, qcfg).reshape(b, sq, kvh, hd)
@@ -650,32 +796,30 @@ def apply_attn_paged_decode(params, x, cfg: ModelConfig, *, pool,
     page = jnp.take_along_axis(page_indices, (steps // ps)[:, None],
                                axis=1)[:, 0]
     off = steps % ps
-    int8_pool = pool["k"].dtype == jnp.int8
+    int8_pool = cfg.kv_cache_bits == 8
     if int8_pool:
         qk, ks = _quantize_kv(k)
         qv, vs = _quantize_kv(v)
         stores = {"k": qk, "v": qv, "ks": ks, "vs": vs}
     else:
         stores = {"k": k, "v": v}
-    new_pool = dict(pool)
-    for name, val in stores.items():
-        new_pool[name] = pool[name].at[page, off].set(
-            val[:, 0].astype(pool[name].dtype))
+    new_pool = _store_rows(pool, layer, page, off,
+                           {n: val[:, 0] for n, val in stores.items()}, cfg)
 
     if kernel is None:
         kernel = cfg.paged_kernel
     if kernel:
         from repro.kernels.paged_attention import paged_attention
         with jax.named_scope("attention"):
-            out = paged_attention(q, new_pool, page_indices, steps, cfg,
-                                  scale)
+            out = paged_attention(
+                q, new_pool["kv"], layer, page_indices, steps, cfg, scale,
+                unpack=functools.partial(unpack_pages,
+                                         layout=pool_layout(cfg), kvh=kvh))
     else:
-        ck = _gather_pages(new_pool["k"], page_indices)
-        cv = _gather_pages(new_pool["v"], page_indices)
-        cks = _gather_pages(new_pool["ks"], page_indices) if int8_pool \
-            else None
-        cvs = _gather_pages(new_pool["vs"], page_indices) if int8_pool \
-            else None
+        names = ("k", "v", "ks", "vs") if int8_pool else ("k", "v")
+        ck, cv, *scales = _gather_pages(new_pool, layer, page_indices, cfg,
+                                        names)
+        cks, cvs = scales if int8_pool else (None, None)
         size = ck.shape[1]
         valid = jnp.arange(size)[None, :] < \
             jnp.minimum(steps + 1, size)[:, None]

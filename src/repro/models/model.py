@@ -102,13 +102,15 @@ def _apply_superblock(bp: Params, x, cfg: ModelConfig, pattern, *,
 
 
 def _apply_superblock_paged(bp: Params, x, cfg: ModelConfig, pattern, *,
-                            pool, mode: str, **attn_kw):
+                            pool, layer, mode: str, **attn_kw):
     """One super-block pass against a page pool (continuous-batching serve).
 
-    ``mode`` is "prefill", "prefill_batched" or "decode"; ``attn_kw``
-    forwards to the paged attention entry point. Residual/MLP structure
-    mirrors :func:`_apply_superblock` exactly — only the KV storage
-    differs."""
+    ``pool`` holds the layer-stacked leaves and ``layer`` is the repeat
+    being applied: each block writes and reads ``[layer, ...]`` of its
+    stacked leaves. ``mode`` is "prefill", "prefill_batched" or "decode";
+    ``attn_kw`` forwards to the paged attention entry point.
+    Residual/MLP structure mirrors :func:`_apply_superblock` exactly —
+    only the KV storage differs."""
     new_pool = {}
     sp = "seq_sp" if cfg.seq_shard else None
     paged_fns = {"prefill": A.apply_attn_paged_prefill,
@@ -121,7 +123,8 @@ def _apply_superblock_paged(bp: Params, x, cfg: ModelConfig, pattern, *,
                 f"{kind!r} in pattern {pattern} (recurrent/cross blocks "
                 f"keep per-slot dense state; see repro.serve)")
         fn = paged_fns[mode]
-        y, npl = fn(bp[f"b{i}"], x, cfg, pool=pool[f"c{i}"], **attn_kw)
+        y, npl = fn(bp[f"b{i}"], x, cfg, pool=pool[f"c{i}"], layer=layer,
+                    **attn_kw)
         x = shard(x + y, "batch", sp, None)
         if f"m{i}" in bp:
             if cfg.family == "moe" and kind == "attn":
@@ -338,8 +341,10 @@ class Model:
         return None
 
     def init_page_pool(self, n_pages: int, page_size: int):
-        """Layer-stacked paged KV pool: leaves (n_repeats, n_pages,
-        page_size, KV, D) (+ scale leaves under KV8). No batch axis — slots
+        """Layer-stacked paged KV pool: one leaf (n_repeats, n_pages
+        rounded up to ``attention.PAGE_TILE``, page_elems) per pattern
+        position, a page's K, V (and KV8 scale) rows side by side in one
+        row (``attention.init_attn_page_pool``). No batch axis — slots
         exist only in the page table the serve engine packs per step."""
         reason = self.supports_paged()
         if reason is not None:
@@ -351,6 +356,26 @@ class Model:
         return {"body": {f"c{i}": stacked
                          for i in range(len(self.pattern))}}
 
+    def _scan_paged(self, params: Params, x, pool, mode: str, **attn_kw):
+        """The layer scan of the paged entry points. The stacked pool
+        rides in the carry, not in ``xs`` -> ``ys``: each layer updates
+        its rows in place at ``[layer, ...]`` and gathers straight from
+        the stack, so no layer slice is cut out, relaid out or written
+        back whole. Returns (x, new pool)."""
+        cfg = self.cfg
+
+        def body(carry, xs):
+            h, pl = carry
+            bp, layer = xs
+            h, pl = _apply_superblock_paged(
+                bp, h, cfg, self.pattern, pool=pl, layer=layer, mode=mode,
+                **attn_kw)
+            return (h, pl), None
+        layers = jnp.arange(cfg.n_repeats, dtype=jnp.int32)
+        (x, new_body), _ = _scan(body, (x, pool["body"]),
+                                 (params["blocks"], layers))
+        return x, {"body": new_body}
+
     def prefill_paged(self, params: Params, tokens, pool, *,
                       prefix_page_ids, write_page_ids, write_offs,
                       write_from: int = 0):
@@ -360,20 +385,12 @@ class Model:
         (``len(prefix_page_ids) * page_size`` positions, gathered from the
         pool). Returns (last-position logits, new pool). Static shapes:
         retraces per (Ls, n_prefix_pages, write_from) combination."""
-        cfg = self.cfg
-
-        def body(carry, xs):
-            bp, pl = xs
-            y, npl = _apply_superblock_paged(
-                bp, carry, cfg, self.pattern, pool=pl, mode="prefill",
-                prefix_page_ids=prefix_page_ids,
-                write_page_ids=write_page_ids, write_offs=write_offs,
-                write_from=write_from)
-            return y, npl
         x = self._embed_tokens(params, tokens)
-        x, new_body = _scan(body, x, (params["blocks"], pool["body"]))
-        logits = self._logits(params, x[:, -1:])
-        return logits, {"body": new_body}
+        x, pool = self._scan_paged(
+            params, x, pool, "prefill", prefix_page_ids=prefix_page_ids,
+            write_page_ids=write_page_ids, write_offs=write_offs,
+            write_from=write_from)
+        return self._logits(params, x[:, -1:]), pool
 
     def prefill_paged_batched(self, params: Params, tokens, pool, *,
                               prefix_page_ids, prefix_lens, suffix_lens,
@@ -385,23 +402,15 @@ class Model:
         :func:`repro.models.attention.apply_attn_paged_prefill_batched`
         for the index-array contract. Returns (per-row last-real-position
         logits (B, 1, V), new pool). Static per (B, Lb, PPb) bucket."""
-        cfg = self.cfg
-
-        def body(carry, xs):
-            bp, pl = xs
-            y, npl = _apply_superblock_paged(
-                bp, carry, cfg, self.pattern, pool=pl,
-                mode="prefill_batched",
-                prefix_page_ids=prefix_page_ids, prefix_lens=prefix_lens,
-                suffix_lens=suffix_lens, write_page_ids=write_page_ids,
-                write_offs=write_offs, write_pos=write_pos)
-            return y, npl
         x = self._embed_tokens(params, tokens)
-        x, new_body = _scan(body, x, (params["blocks"], pool["body"]))
+        x, pool = self._scan_paged(
+            params, x, pool, "prefill_batched",
+            prefix_page_ids=prefix_page_ids, prefix_lens=prefix_lens,
+            suffix_lens=suffix_lens, write_page_ids=write_page_ids,
+            write_offs=write_offs, write_pos=write_pos)
         last = jnp.take_along_axis(
             x, (suffix_lens - 1)[:, None, None].astype(jnp.int32), axis=1)
-        logits = self._logits(params, last)
-        return logits, {"body": new_body}
+        return self._logits(params, last), pool
 
     def decode_step_paged(self, params: Params, pool, tokens, page_indices,
                           steps, kernel: bool | None = None):
@@ -411,18 +420,11 @@ class Model:
         retraces as requests come and go. ``kernel`` (static under jit)
         selects the Pallas live-page attention path; None defers to
         ``cfg.paged_kernel``."""
-        cfg = self.cfg
-
-        def body(carry, xs):
-            bp, pl = xs
-            y, npl = _apply_superblock_paged(
-                bp, carry, cfg, self.pattern, pool=pl, mode="decode",
-                page_indices=page_indices, steps=steps, kernel=kernel)
-            return y, npl
         x = self._embed_tokens(params, tokens)
-        x, new_body = _scan(body, x, (params["blocks"], pool["body"]))
-        logits = self._logits(params, x)
-        return logits, {"body": new_body}
+        x, pool = self._scan_paged(
+            params, x, pool, "decode", page_indices=page_indices,
+            steps=steps, kernel=kernel)
+        return self._logits(params, x), pool
 
     def prefill(self, params: Params, batch: dict, max_len: int):
         """Process the prompt, fill caches; returns (last-pos logits, caches)."""

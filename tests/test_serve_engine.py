@@ -282,6 +282,112 @@ def test_kv8_shares_bytes_recomputes_activations(cache):
         np.testing.assert_array_equal(np.asarray(r.tokens), ref)
 
 
+# -- the stacked pool's bytes ------------------------------------------------
+
+POOL_BYTES = os.path.join(ROOT, "tests", "data", "pool_bytes.npz")
+
+
+@pytest.mark.parametrize("kv8", [False, True], ids=["exact", "kv8"])
+def test_pool_bytes_match_unfolded_layout(kv8, cache):
+    """After staggered arrivals, shared prefixes and decode across page
+    boundaries, every (layer, page, offset) of the stacked pool holds the
+    bytes that the unfolded (n_repeats, n_pages, page_size, KV, D) pool
+    held after the same run (recorded from it in ``POOL_BYTES``), the
+    null page and the rounding pages stay zero, and the tokens equal the
+    per-request ``greedy_generate`` stream."""
+    from repro.models import attention as A
+    cfg = get_reduced("smollm_135m").replace(n_layers=2)
+    if kv8:
+        cfg = serve_config(cfg, backend="int_dot")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    max_len, gen = 16, 5
+    eng = ServeEngine(model, params, n_slots=2, max_len=max_len,
+                      page_size=4)
+    prompts = _prompts(cfg, plen=6, n=4, seed=5)
+    for p in prompts[:2]:
+        eng.submit(p, gen)
+    for _ in range(3):
+        eng.step()
+    for p in prompts[2:]:
+        eng.submit(p, gen)
+    done = eng.run()
+    assert len(done) == 4 and eng.counters["pages_shared"] > 0
+    for r in done:
+        ref = _reference(model, params, list(r.prompt), max_len, gen)
+        np.testing.assert_array_equal(np.asarray(r.tokens), ref)
+    (leaf,) = jax.tree.leaves(eng.pool)
+    assert leaf.shape[1] % A.PAGE_TILE == 0
+    segs = A.unpack_pages(leaf, A.pool_layout(cfg), cfg.n_kv_heads)
+    recorded = np.load(POOL_BYTES)
+    tag = "kv8" if kv8 else "exact"
+    assert sorted(segs) == sorted(k[len(tag) + 1:] for k in recorded
+                                  if k.startswith(tag + "_"))
+    for name, a in segs.items():
+        a = np.asarray(a)
+        if a.dtype == jnp.bfloat16:
+            a = a.view(np.uint16)
+        want = recorded[f"{tag}_{name}"]
+        np.testing.assert_array_equal(a[:, 1:eng.n_pages], want[:, 1:],
+                                      err_msg=name)
+        assert not a[:, 0].any() and not a[:, eng.n_pages:].any(), name
+
+
+@pytest.mark.parametrize("group,page,off", [
+    # decode: one lane a slot, several lanes to one page, one to the null
+    # page
+    (1, [3, 3, 3, 5, 0, 7], [0, 2, 3, 1, 1, 3]),
+    # prefill: runs of page_size lanes through whole pages, a short last
+    # run padded with null-page lanes; the lane of the last run that names
+    # another page than the run's first is not written
+    (4, [3, 3, 3, 3, 5, 5, 0, 0, 6, 6, 2, 6], [0, 1, 2, 3, 0, 1, 0, 0,
+                                                0, 1, 2, 3]),
+], ids=["decode", "prefill"])
+@pytest.mark.parametrize("kv8", [False, True], ids=["exact", "kv8"])
+def test_store_rows_matches_unfolded_writes(kv8, group, page, off):
+    """Rows written through the stacked leaf read back as the same rows
+    written into an unfolded (L, n_pages, page_size, KV, width) pool, and
+    every other byte of the leaf is left as it was."""
+    from repro.models import attention as A
+    cfg = get_reduced("smollm_135m").replace(n_layers=2)
+    if kv8:
+        cfg = serve_config(cfg, backend="int_dot")
+    layout = A.pool_layout(cfg)
+    kvh, ps, n_pages, n_layers = cfg.n_kv_heads, 4, 9, 3
+    one = A.init_attn_page_pool(cfg, n_pages, ps)["kv"]
+    rng = np.random.default_rng(0)
+    leaf = jnp.asarray(rng.integers(-100, 100, (n_layers,) + one.shape)
+                       .astype(np.int8)) if kv8 else jnp.asarray(
+        rng.standard_normal((n_layers,) + one.shape), one.dtype)
+    n = len(page)
+    rows = {name: jnp.asarray(rng.standard_normal((n, kvh, w)), dt)
+            if jnp.issubdtype(dt, jnp.floating) else
+            jnp.asarray(rng.integers(-128, 128, (n, kvh, w)), dt)
+            for name, dt, w in layout}
+    new = A._store_rows({"kv": leaf}, jnp.int32(1),
+                        jnp.asarray(page, jnp.int32),
+                        jnp.asarray(off, jnp.int32), rows, cfg,
+                        group)["kv"]
+    before = A.unpack_pages(leaf, layout, kvh)
+    after = A.unpack_pages(new, layout, kvh)
+    pg, of = np.asarray(page), np.asarray(off)
+    first = np.repeat(pg[::group], group)
+    live = (pg != 0) & (pg == first)
+    for name, *_ in layout:
+        want = np.asarray(before[name]).copy()
+        want[1, pg[live], of[live]] = np.asarray(rows[name])[live]
+        got = np.asarray(after[name])
+        assert got.tobytes() == want.tobytes(), name
+    gathered = A._gather_pages({"kv": new}, jnp.int32(1),
+                               jnp.asarray([[3, 5], [7, 0]], jnp.int32),
+                               cfg, ("k", "v"))
+    for g, name in zip(gathered, ("k", "v")):
+        full = np.asarray(after[name])[1]
+        want = np.stack([np.concatenate([full[3], full[5]]),
+                         np.concatenate([full[7], full[0]])])
+        assert np.asarray(g).tobytes() == want.tobytes(), name
+
+
 # -- scheduler ---------------------------------------------------------------
 
 def test_more_requests_than_slots(fp_cell):

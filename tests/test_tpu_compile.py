@@ -12,6 +12,7 @@ process at a time may load the TPU library, and pytest-xdist workers all
 import this file.
 """
 import functools
+import math
 import os
 import re
 
@@ -120,6 +121,54 @@ def test_bucketed_prefill_compiles_for_v5e(compiled):
     """One bucketed batched-prefill program: 8 rows x 256 positions, no
     shared prefix, pool donated."""
     _check_fits(compiled["prefill"])
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                    r"\S* ([\w\-]+)\(([^)]*)\)", re.M)
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "u16": 2, "f32": 4, "s32": 4, "u32": 4}
+
+
+def _pool_moves(text: str, pool) -> list[str]:
+    """Instructions that move pool-sized data: a ``copy`` or
+    ``dynamic-slice`` whose result, or a ``dynamic-update-slice`` whose
+    update, has a page-count dimension and is at least one layer's
+    smallest pool leaf. Fusion bodies are searched too."""
+    leaves = jax.tree.leaves(pool)
+    n_pages = leaves[0].shape[1]
+    least = min(math.prod(a.shape[1:]) * a.dtype.itemsize for a in leaves)
+    shapes = {m[0]: (m[1], [int(d) for d in m[2].split(",") if d])
+              for m in _INSTR.findall(text)}
+    out = []
+    for name, dt, dims, op, args in _INSTR.findall(text):
+        if op == "dynamic-update-slice":
+            update = args.split(",")[1].strip().lstrip("%")
+            dt, dims = shapes.get(update, (dt, []))
+        elif op in ("copy", "dynamic-slice"):
+            dims = [int(d) for d in dims.split(",") if d]
+        else:
+            continue
+        nbytes = math.prod(dims) * _ITEMSIZE.get(dt, 4)
+        if n_pages in dims and nbytes >= least:
+            out.append(f"{name} {op} {dt}{dims}")
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "prefill_shared"])
+def test_pool_updated_in_place(served, compiled, program):
+    """The layer scan carries the stacked pool and updates it in place:
+    no leaf or layer slice of it is copied, relaid out or written back
+    whole, and the program's temporaries stay below one stacked pool."""
+    pool = served[2]
+    prog = compiled[program]
+    text = prog.as_text()
+    n_pages = jax.tree.leaves(pool)[0].shape[1]
+    assert any(f",{n_pages}," in f",{dims}," for _, _, dims, _, _
+               in _INSTR.findall(text)), "the pool's instructions parse"
+    assert _pool_moves(text, pool) == []
+    stacked = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(pool))
+    assert prog.memory_analysis().temp_size_in_bytes < stacked
 
 
 @pytest.mark.parametrize("program,scopes", [
