@@ -17,7 +17,8 @@ what ``tracereduce.py`` reduces from the harness's spans:
   scoped_ops    {``<program>/<scope>/<op>`` (``<program>/<op>`` when
                 unscoped): op self seconds}
 
-A device op's scope is the innermost of ``SCOPES`` in its ``tf_op`` stat
+A device op's scope is the innermost of ``SCOPES`` (and of any scopes
+a configuration adds) in its ``tf_op`` stat
 (the name-scope path; a fusion's is that of its root op). The stat sits
 in the event *metadata* of the device plane, which
 ``jax.profiler.ProfileData`` does not expose, so ``op_scopes`` reads it
@@ -26,10 +27,12 @@ from the ``.xplane.pb`` protobuf with a small wire-format reader.
     python3 benchmarks/chip/phases.py --workload <cell> --seed <n> \\
         --seconds <s>
 
-runs a cell as ``run.py --trace 1`` does, reduces the same trace with
-this module too, and prints one JSON line: the run's per-layer metrics
-and the split of ``engine_host_ms`` by phase and of ``decode_step_ms``
-by scope.
+runs a cell as ``run.py --trace 1`` does (which reduces its trace with
+this module too, for the ``host_<phase>_ms`` and ``decode_<scope>_ms``
+metrics) and prints one JSON line: the run's per-layer metrics, the
+split of ``engine_host_ms`` by phase and of ``decode_step_ms`` by scope,
+the ten ops that took most time by scope, and every program's time by
+scope.
 """
 from __future__ import annotations
 
@@ -137,11 +140,15 @@ def op_scopes(path: str) -> list[tuple[str, dict]]:
 # -- reduction ---------------------------------------------------------------
 
 def events(path: str, base=tr.events) -> dict:
-    """``tracereduce.events`` (or ``base``) with the engine's spans
+    """``tracereduce.events`` (or ``base``) of the trace, ``annotate``d."""
+    return annotate(path, base(path))
+
+
+def annotate(path: str, ev: dict) -> dict:
+    """``ev``, the trace's ``tracereduce.events``, with the engine's spans
     (``engine_spans``) and, on each device, its ops' ``tf_op`` paths
     (``tf_op``)."""
     from jax.profiler import ProfileData
-    ev = base(path)
     spans = {n: [] for n in PHASES}
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:"):
@@ -155,11 +162,11 @@ def events(path: str, base=tr.events) -> dict:
     return ev
 
 
-def scope_of(tf_op: str | None) -> str:
-    """The innermost of ``SCOPES`` in a name-scope path, or
+def scope_of(tf_op: str | None, scopes=SCOPES) -> str:
+    """The innermost of ``scopes`` in a name-scope path, or
     ``unscoped``."""
     parts = (tf_op or "").split("/")
-    return next((p for p in reversed(parts) if p in SCOPES), "unscoped")
+    return next((p for p in reversed(parts) if p in scopes), "unscoped")
 
 
 def program_id(module: str) -> int | None:
@@ -194,9 +201,9 @@ def innermost(spans) -> list[tuple[int, int, str]]:
     return out
 
 
-def reduce(ev: dict) -> dict | None:
-    """The numbers of the engine's phases and the model's scopes in one
-    traced window (``tracereduce``'s window); None where
+def reduce(ev: dict, scopes=SCOPES) -> dict | None:
+    """The numbers of the engine's phases and the model's ``scopes`` in
+    one traced window (``tracereduce``'s window); None where
     ``tracereduce.reduce`` gives None. A phase that recorded no span has
     no entry in ``phase_idle_s``."""
     base = tr.reduce(ev)
@@ -243,7 +250,7 @@ def reduce(ev: dict) -> dict | None:
             i = bisect.bisect_right(starts, s) - 1
             mod = mods[i][2] if i >= 0 and s < mods[i][1] else None
             owner = tr.program_name(mod) if mod else "?"
-            scope = scope_of(tf_op.get((program_id(mod or ""), n)))
+            scope = scope_of(tf_op.get((program_id(mod or ""), n)), scopes)
             per = scope_s.setdefault(owner, {})
             per[scope] = per.get(scope, 0.0) + own * 1e-9
             key = "/".join([owner] + [scope] * (scope != "unscoped")
@@ -272,8 +279,10 @@ def breakdown(red: dict) -> dict:
 def split(base: dict, red: dict) -> dict:
     """Per traced step, milliseconds: ``host_<phase>_ms``, the device-idle
     time whose innermost span is that phase, and ``decode_<scope>_ms``,
-    op self time in that scope per ``_decode_fn`` execution. A phase or
-    scope the trace does not hold is left out."""
+    op self time in that scope per ``_decode_fn`` execution, for each of
+    ``SCOPES`` (0 where it ran nothing), each other scope the reduction
+    found, and ``unscoped``. A phase the trace does not hold is left out,
+    and so are the scopes of a decode in which no op had one."""
     out = {}
     n_steps = len(base["spans"]["step"])
     for phase, idle in red["phase_idle_s"].items():
@@ -281,8 +290,8 @@ def split(base: dict, red: dict) -> dict:
             out[f"host_{phase.split('.')[1]}_ms"] = sum(idle) / n_steps * 1e3
     runs = base["programs"].get("_decode_fn", [0.0, 0])[1]
     per = red["scope_s"].get("_decode_fn", {})
-    if runs and set(per) & set(SCOPES):
-        for scope in SCOPES + ("unscoped",):
+    if runs and set(per) - {"unscoped"}:
+        for scope in dict.fromkeys(SCOPES + tuple(per) + ("unscoped",)):
             out[f"decode_{scope}_ms"] = per.get(scope, 0.0) / runs * 1e3
     return out
 
@@ -297,13 +306,13 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
+    import phases as used        # the copy of this module that run.py uses
     seen = {}
 
-    def traced(path, base=tr.events):
-        ev = events(path, base)
-        seen["base"], seen["red"] = tr.reduce(ev), reduce(ev)
-        return ev
-    tr.events = traced
+    def kept(ev, scopes=SCOPES):
+        seen["base"], seen["red"] = tr.reduce(ev), reduce(ev, scopes)
+        return seen["red"]
+    used.reduce = kept
     files = run.cell_files(args.workload)
     try:
         result = run.run_cell(files, args.seed, args.seconds, True,

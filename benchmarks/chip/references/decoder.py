@@ -12,6 +12,11 @@ never has more than one float32 layer on the chip. ``lowbits`` runs the
 control: the same pass with each linear's input and the stored K/V
 rounded per token to that many bits, the step below the served
 precision's 8.
+
+For the harness (``run.reference``) the module also says what the
+architecture is: ``program_dims``, and ``builder``, ``program_params``
+and ``counts``, which are ``weights.py``'s and ``counts.py``'s; and
+``layer_step``, the pass over one layer, which ``rehearse.py`` compiles.
 """
 from __future__ import annotations
 
@@ -20,7 +25,21 @@ import functools
 import jax
 import jax.numpy as jnp
 
+import counts  # noqa: F401  (the harness's operation and byte counts)
 import weights as W
+from weights import builder, program_params  # noqa: F401
+
+
+def program_dims(cfg) -> dict:
+    """Each key that a decoder configuration's ``dims`` may hold, as the
+    program's ``ModelConfig`` has it. The program has no QKV bias."""
+    return {"d_model": cfg.d_model, "d_ff": cfg.d_ff,
+            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.hd, "n_layers": cfg.n_layers,
+            "vocab": cfg.vocab, "norm_eps": cfg.norm_eps,
+            "rope_theta": cfg.rope_theta,
+            "rope_fraction": 0.5 if cfg.rope_2d else 1.0,
+            "tied": cfg.tie_embeddings, "qkv_bias": False}
 
 
 def _round(x, bits: int):
@@ -81,7 +100,9 @@ def _block(x, lin, dims, lowbits):
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "bits", "lowbits"))
-def _layer_step(x, key, index, dims, bits, lowbits):
+def layer_step(x, key, index, dims, bits, lowbits):
+    """Layer ``index`` over x (B, S, d_model) float32; ``dims`` as sorted
+    items."""
     dims = dict(dims)
     lin = {n: q.astype(jnp.float32) * s
            for n, (q, s) in W.layer(key, index, dims, bits).items()}
@@ -115,7 +136,7 @@ def _hidden(seed, fdims, bits, tokens, lowbits):
     x = jnp.take(_tables(key, fdims)["embed"], tokens,
                  axis=0).astype(jnp.float32)
     for i in range(dict(fdims)["n_layers"]):
-        x = _layer_step(x, key, i, fdims, bits, lowbits)
+        x = layer_step(x, key, i, fdims, bits, lowbits)
     return x
 
 

@@ -8,8 +8,9 @@ For each cell (all of ``BENCHMARK.json`` by default; ``--slots`` in
 place of the configuration's slot count) it compiles, for one
 chip of a described ``v5e:2x2``: the weight build, the packed decode, and
 every batched prefill bucket (batch bucket x length bucket) that the
-cell's mix and slot count can ask for, and the reference's layer step at
-its largest sample. It prints ``memory_analysis()`` of each: arguments,
+cell's mix and slot count can ask for, and the reference's
+``layer_step`` at its largest sample (a reference without one is named
+as not compiled). It prints ``memory_analysis()`` of each: arguments,
 outputs, temporaries, and the sum of arguments and temporaries against
 the chip's 16 GiB. The compiler refuses here what it would refuse there.
 """
@@ -25,7 +26,6 @@ import jax                                              # noqa: E402
 import jax.numpy as jnp                                 # noqa: E402
 
 import run                                              # noqa: E402
-import weights                                          # noqa: E402
 from traffic import sizes                               # noqa: E402
 
 GIB = 2 ** 30
@@ -65,10 +65,11 @@ def main(cells, slots: int = 0) -> int:
         conf, mix = files["conf"], files["traffic"]
         dims = run.dims_of(conf)
         bits = conf["program"]["serve"]["w_bits"]
-        model = run.program(conf, dims)
+        ref = run.reference(files)
+        model = run.program(conf, dims, ref)
         e = {**conf["engine"], **({"n_slots": slots} if slots else {})}
         tag = f"{conf['name']} ({e['n_slots']} slots)"
-        build = jax.jit(weights.builder(dims, bits))
+        build = jax.jit(ref.builder(dims, bits))
         key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
         params = spec(jax.eval_shape(build, jax.random.PRNGKey(0)))
         n_pages = e["n_slots"] * e["max_len"] // e["page_size"] + 1
@@ -101,12 +102,15 @@ def main(cells, slots: int = 0) -> int:
         s_pad = run.ref_length(mix)
         if (tag, "ref", s_pad) not in done:
             done.add((tag, "ref", s_pad))
-            from references import decoder
+            if not hasattr(ref, "layer_step"):
+                print(f"| {tag} reference layer | not compiled: "
+                      f"{files['reference'].name} has no layer_step |")
+                continue
             x = jax.ShapeDtypeStruct((run.SAMPLE_ROWS, s_pad,
                                       dims["d_model"]), jnp.float32,
                                      sharding=one)
             report(f"{tag} reference layer {run.SAMPLE_ROWS}x{s_pad}",
-                   decoder._layer_step, x, key,
+                   ref.layer_step, x, key,
                    jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
                    dims=tuple(sorted(dims.items())), bits=bits, lowbits=0)
     return 0

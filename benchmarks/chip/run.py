@@ -7,12 +7,14 @@
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<mix>.json``),
 which the module of its ``kind`` (``traffic/<kind>.py``) turns into
-load. The run builds the served model from the configuration, draws its
-weights on the device from the seed, warms every program the mix can
-ask for, and then drives ``ServeEngine`` with the mix for
-``--seconds``. It checks what the window served against the
-plain float32 reference of the configuration (limits in
-``limits/<cell>.json``) and prints one JSON line last.
+load. The configuration names its reference (``references/<name>.py``,
+see ``reference``), which says what the architecture is: the run builds
+the served model from the configuration, checks its sizes and draws its
+weights on the device from the seed through that module, warms every
+program the mix can ask for, and then drives ``ServeEngine`` with the
+mix for ``--seconds``. It checks what the window served against the
+reference's plain float32 pass (limits in ``limits/<cell>.json``) and
+prints one JSON line last.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 profiles the last few seconds of the window and reports the cell's
@@ -34,6 +36,7 @@ import os                                             # noqa: E402
 import shutil                                         # noqa: E402
 import sys                                            # noqa: E402
 import tempfile                                       # noqa: E402
+import traceback                                      # noqa: E402
 import types                                          # noqa: E402
 from pathlib import Path                              # noqa: E402
 
@@ -75,10 +78,24 @@ def cell_files(name: str) -> dict:
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
     if cell is None:
         raise SystemExit(f"run.py: no workload {name!r} in BENCHMARK.json")
-    conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    return {"bench": bench, "cell": cell, "conf": load(ROOT / conf["file"]),
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    conf = load(ROOT / entry["file"])
+    return {"bench": bench, "cell": cell, "conf": conf,
             "traffic": load(HERE / "traffic" / f"{cell['traffic']}.json"),
-            "limits": HERE / "limits" / f"{name}.json"}
+            "limits": HERE / "limits" / f"{name}.json",
+            "reference": HERE / "references" / f"{conf['reference']}.py"}
+
+
+def reference(files: dict) -> types.ModuleType:
+    """The configuration's reference module, the one place that says what
+    its architecture is: ``program_dims(cfg)`` (each ``dims`` key as the
+    program's ``ModelConfig`` has it), ``builder(dims, bits)`` and
+    ``program_params(seed, dims, bits)`` (the served pytree, drawn on the
+    device), ``counts`` (operations and bytes of a decode step and a
+    prefill, as ``counts.py``) and ``gaps(...)`` (the comparison). It may
+    give ``layer_step(x, key, index, dims, bits, lowbits)``, its pass over
+    one layer, for ``rehearse.py`` to compile against the chip's memory."""
+    return module(files["reference"])
 
 
 def traffic_kind(mix: dict) -> types.ModuleType:
@@ -115,25 +132,63 @@ def compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def program(conf: dict, dims: dict):
-    """The served model as the configuration file states it."""
+def program(conf: dict, dims: dict, ref: types.ModuleType):
+    """The served model as the configuration file states it: every key of
+    ``dims`` must be what the reference reads from the program's config
+    (``ref.program_dims``)."""
     from repro.configs import get_config
     from repro.launch.specs import serve_config
     from repro.models.model import Model
     p = conf["program"]
     cfg = serve_config(get_config(p["arch"]).replace(**p["overrides"]),
                        **p["serve"])
-    have = {"d_model": cfg.d_model, "d_ff": cfg.d_ff,
-            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.hd, "n_layers": cfg.n_layers,
-            "vocab": cfg.vocab, "norm_eps": cfg.norm_eps,
-            "rope_theta": cfg.rope_theta,
-            "rope_fraction": 0.5 if cfg.rope_2d else 1.0,
-            "tied": cfg.tie_embeddings, "qkv_bias": False}
+    have = ref.program_dims(cfg)
+    unknown = sorted(set(dims) - set(have))
+    if unknown:
+        raise ValueError(f"dims key(s) {unknown} are not stated by the "
+                         f"reference's program_dims")
     wrong = {k: (have[k], v) for k, v in dims.items() if have[k] != v}
     if wrong:
         raise ValueError(f"program config differs from the file: {wrong}")
     return Model(cfg)
+
+
+def served_params(ref: types.ModuleType, model, seed: int, dims: dict,
+                  bits: int):
+    """The reference's draw of the served weights, on the device, checked
+    against the program's pytree, shapes and dtypes."""
+    import jax
+
+    import weights
+    params = ref.program_params(seed, dims, bits)
+    weights.check_layout(params, jax.eval_shape(model.init,
+                                                jax.random.PRNGKey(0)))
+    return params
+
+
+def trace_numbers(path: str, conf: dict) -> dict | None:
+    """``tracereduce.reduce`` of a trace, with the engine's phases and the
+    model's scopes (``phases.reduce``) added as ``scope_s``,
+    ``phase_idle_s`` and ``idle_by_phase``; None where ``tracereduce``
+    finds nothing. The scopes are ``phases.SCOPES`` and any that the
+    configuration lists under ``"scopes"``. Where the phases cannot be
+    read, it says so on stderr and leaves those keys out."""
+    import phases
+    import tracereduce as tr
+    ev = tr.events(path)
+    red = tr.reduce(ev)
+    if red is None:
+        return None
+    scopes = phases.SCOPES + tuple(conf.get("scopes", ()))
+    try:
+        ph = phases.reduce(phases.annotate(path, ev), scopes)
+    except Exception:            # the run still reports every other metric
+        print("[trace] phases and scopes not read:", file=sys.stderr)
+        traceback.print_exc()
+        return red
+    red.update({k: ph[k] for k in ("scope_s", "phase_idle_s",
+                                   "idle_by_phase")})
+    return red
 
 
 class CompileClock:
@@ -207,7 +262,6 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
 
     import drive
     import tracereduce
-    import weights
     from traffic import sizes
     from repro.serve import ServeEngine
     dev = devices(chips)
@@ -215,10 +269,9 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
     conf, mix, cell = files["conf"], files["traffic"], files["cell"]
     dims = dims_of(conf)
     bits = conf["program"]["serve"]["w_bits"]
-    model = program(conf, dims)
-    params = weights.program_params(seed, dims, bits)
-    weights.check_layout(params, jax.eval_shape(model.init,
-                                                jax.random.PRNGKey(0)))
+    ref = reference(files)
+    model = program(conf, dims, ref)
+    params = served_params(ref, model, seed, dims, bits)
     e = conf["engine"]
     eng = ServeEngine(model, params, n_slots=e["n_slots"],
                       max_len=e["max_len"], page_size=e["page_size"])
@@ -260,6 +313,7 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
     ctx = types.SimpleNamespace(
         setup_s=setup_s, window=win, tracks=tracks, attempted=attempted,
         steps=drv.steps, dims=dims, bits=bits, trace=None,
+        counts=ref.counts,
         peak=load(HERE / "peaks.json")["devices"].get(dev["kind"]),
         stop_s=win["stop"])
     result = {"correct": False, "attempted": len(attempted),
@@ -274,9 +328,8 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
     result["window_compiles"] = clock.events
 
     if trace:
-        tr = tracereduce
         paths = list(Path(tdir).glob("plugins/profile/*/*.xplane.pb"))
-        red = tr.reduce(tr.events(str(paths[0]))) if paths else None
+        red = trace_numbers(str(paths[0]), conf) if paths else None
         shutil.rmtree(tdir, ignore_errors=True)
         if red is not None:
             t0, t1 = tstate["start"], tstate["stop"]
@@ -285,7 +338,7 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
                          if s.start >= t0 and s.end <= t1]
             dev["busy_s"] = red["busy_s"]
             dev["window_s"] = red["window_s"]
-            result["breakdown"] = tr.breakdown(red)
+            result["breakdown"] = tracereduce.breakdown(red)
     kind = "per_layer" if trace else "end_to_end"
     for m in files["bench"][kind]:
         if files["cell"]["name"] not in m.get("workloads",
@@ -299,7 +352,6 @@ def run_cell(files: dict, seed: int, seconds: float, trace: bool,
     # the reference runs on a chip the program has left
     del eng, drv, params, stats
     gc.collect()
-    ref = module(HERE / "references" / f"{conf['reference']}.py")
     length = ref_length(mix)
     gaps = np.concatenate(ref.gaps(seed, dims, bits, samples, length,
                                    SAMPLE_ROWS) if samples else [[]])

@@ -34,7 +34,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     import jax
 
-    import weights
     from repro.serve import ServeEngine
     files = run.cell_files(args.workload)
     try:
@@ -45,9 +44,10 @@ def main(argv=None) -> int:
     run.compile_cache()
     conf = files["conf"]
     dims = run.dims_of(conf)
-    model = run.program(conf, dims)
-    params = weights.program_params(args.seed, dims,
-                                    conf["program"]["serve"]["w_bits"])
+    ref = run.reference(files)
+    model = run.program(conf, dims, ref)
+    params = run.served_params(ref, model, args.seed, dims,
+                               conf["program"]["serve"]["w_bits"])
     e = conf["engine"]
     rng = np.random.default_rng(args.seed)
     print("| slots | ms a step | tokens/s | device GB in use |\n"

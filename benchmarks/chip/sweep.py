@@ -31,7 +31,6 @@ def main(argv=None) -> int:
     import jax
 
     import drive
-    import weights
     from traffic import sizes
     from repro.serve import ServeEngine
     files = run.cell_files(args.workload)
@@ -43,9 +42,10 @@ def main(argv=None) -> int:
     run.compile_cache()
     conf, mix = files["conf"], files["traffic"]
     dims = run.dims_of(conf)
-    model = run.program(conf, dims)
-    params = weights.program_params(args.seed, dims,
-                                    conf["program"]["serve"]["w_bits"])
+    ref = run.reference(files)
+    model = run.program(conf, dims, ref)
+    params = run.served_params(ref, model, args.seed, dims,
+                               conf["program"]["serve"]["w_bits"])
     e = conf["engine"]
     eng = ServeEngine(model, params, n_slots=e["n_slots"],
                       max_len=e["max_len"], page_size=e["page_size"])
